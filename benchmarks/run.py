@@ -32,6 +32,8 @@ def main(argv=None) -> int:
     ap.add_argument("--only", default=None, help="comma-separated subset")
     ap.add_argument("--json", default="results/benchmarks.json")
     args = ap.parse_args(argv)
+    from repro.launch.runtime import setup_compile_cache
+    setup_compile_cache()
 
     names = args.only.split(",") if args.only else MODULES
     print("name,us_per_call,derived")

@@ -40,9 +40,13 @@ NEG = -1e30
 
 
 def _decode_kernel(idx_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
-                   *, scale: float, bk: int, nk: int):
+                   *, scale: float, bk: int, nk: int, smax: int):
+    """``smax`` is the ring length; when it is not a multiple of ``bk`` the
+    last block runs past the cache, and its rows past ``smax`` (unspecified
+    memory) are masked out of the scores and zeroed in V."""
     b = pl.program_id(0)
     ki = pl.program_id(2)
+    ragged = smax % bk != 0
 
     @pl.when(ki == 0)
     def _init():
@@ -54,6 +58,8 @@ def _decode_kernel(idx_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
     G = q_ref.shape[2]
     slot = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (G, bk), 1)
     ok = slot <= index
+    if ragged:
+        ok &= slot < smax
 
     # skip blocks entirely past this row's valid region
     @pl.when(ki * bk <= index)
@@ -70,6 +76,9 @@ def _decode_kernel(idx_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
         alpha = jnp.exp(m_prev - m_new)
         l_new = alpha * l_prev + jnp.sum(p, axis=1)
         v = v_ref[0, 0].astype(jnp.float32)
+        if ragged:
+            row = ki * bk + jax.lax.broadcasted_iota(jnp.int32, v.shape, 0)
+            v = jnp.where(row < smax, v, 0.0)
         acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot(
             p, v, preferred_element_type=jnp.float32)
         m_ref[...] = jnp.broadcast_to(m_new[:, None], m_ref.shape)
@@ -85,12 +94,13 @@ def _decode_kernel(idx_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
 def decode_attention_bkgd(q, k_cache, v_cache, index, *, block_k: int = 512,
                           interpret: bool = False):
     """q: (B, KV, G, hd); caches: (B, KV, Smax, hd); index: scalar or (B,)
-    int32 — each batch row is masked against its own position."""
+    int32 — each batch row is masked against its own position.  Smax need
+    not be a multiple of ``block_k``: the last block is then partial and
+    masked in the kernel."""
     B, KV, G, hd = q.shape
     Smax = k_cache.shape[2]
     bk = min(block_k, Smax)
-    assert Smax % bk == 0, (Smax, bk)
-    nk = Smax // bk
+    nk = -(-Smax // bk)
     idx = jnp.broadcast_to(jnp.asarray(index, jnp.int32).reshape(-1), (B,))
 
     def kv_map(b, h, ki, idx_ref):
@@ -99,7 +109,8 @@ def decode_attention_bkgd(q, k_cache, v_cache, index, *, block_k: int = 512,
         last = jnp.minimum(idx_ref[b] // bk, nk - 1)
         return (b, h, jnp.minimum(ki, last), 0)
 
-    kernel = functools.partial(_decode_kernel, scale=hd ** -0.5, bk=bk, nk=nk)
+    kernel = functools.partial(_decode_kernel, scale=hd ** -0.5, bk=bk, nk=nk,
+                               smax=Smax)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(B, KV, nk),
@@ -136,7 +147,7 @@ def _decode_paged_kernel(tbl_ref, idx_ref, q_ref, k_ref, v_ref, o_ref,
     (the index maps below translate logical block ki through the table)."""
     del tbl_ref
     _decode_kernel(idx_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref,
-                   l_ref, scale=scale, bk=bk, nk=nk)
+                   l_ref, scale=scale, bk=bk, nk=nk, smax=nk * bk)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))

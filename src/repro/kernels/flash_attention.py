@@ -25,7 +25,7 @@ NEG = -1e30
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
                   scale: float, causal: bool, window, bq: int, bk: int,
-                  nk: int):
+                  nk: int, kv_len: int):
     qi, ki = pl.program_id(2), pl.program_id(3)
 
     @pl.when(ki == 0)
@@ -41,11 +41,13 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
         ok &= k_pos <= q_pos
     if window is not None:
         ok &= k_pos > q_pos - window
+    if kv_len < nk * bk:
+        ok &= k_pos < kv_len
 
     # Tile-level skip: causal/window tiles that are fully masked cost nothing.
     q_lo, q_hi = qi * bq, qi * bq + bq - 1
     k_lo, k_hi = ki * bk, ki * bk + bk - 1
-    live = jnp.asarray(True)
+    live = jnp.asarray(k_lo < kv_len)
     if causal:
         live &= k_lo <= q_hi
     if window is not None:
@@ -79,11 +81,14 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 
 @functools.partial(
     jax.jit, static_argnames=("causal", "window", "block_q", "block_k",
-                              "interpret"))
+                              "kv_len", "interpret"))
 def flash_attention_bhsd(q, k, v, *, causal: bool = True, window=None,
                          block_q: int = 128, block_k: int = 128,
-                         interpret: bool = False):
-    """q: (B, H, Sq, hd); k/v: (B, KV, Sk, hd) → (B, H, Sq, hd)."""
+                         kv_len: int | None = None, interpret: bool = False):
+    """q: (B, H, Sq, hd); k/v: (B, KV, Sk, hd) → (B, H, Sq, hd).
+
+    ``kv_len`` < Sk masks the keys past it: k/v were padded to a whole
+    number of blocks (``ops.flash_attention``)."""
     B, H, Sq, hd = q.shape
     KV, Sk = k.shape[1], k.shape[2]
     G = H // KV
@@ -94,7 +99,8 @@ def flash_attention_bhsd(q, k, v, *, causal: bool = True, window=None,
     grid = (B, H, nq, nk)
 
     kernel = functools.partial(_flash_kernel, scale=hd ** -0.5, causal=causal,
-                               window=window, bq=bq, bk=bk, nk=nk)
+                               window=window, bq=bq, bk=bk, nk=nk,
+                               kv_len=Sk if kv_len is None else kv_len)
     return pl.pallas_call(
         kernel,
         grid=grid,

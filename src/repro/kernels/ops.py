@@ -1,10 +1,11 @@
 """jit'd public wrappers around the Pallas kernels.
 
 Model code calls these with model-native layouts; the wrappers transpose to
-kernel layouts, pick interpret mode automatically (Pallas TPU kernels execute
-their body in Python on CPU when interpret=True — that is how this
-container validates them), and fall back to the jnp reference for shapes the
-kernels don't tile (ragged block sizes).
+kernel layouts, pad ragged lengths to whole blocks (the kernels mask the
+padding), and pick the execution mode: compiled on a TPU backend,
+interpreted on the CPU backend (Pallas TPU kernels execute their body in
+Python when interpret=True — the CPU test path), and an error on any
+other backend, so no run can leave the chip path without a word.
 """
 from __future__ import annotations
 
@@ -24,24 +25,42 @@ from repro.kernels.ssm_scan import ssm_scan_ssd
 
 
 def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(f"Pallas TPU kernels need a TPU (compiled) or the "
+                       f"CPU backend (interpreted); got {backend!r}")
+
+
+def _blocks(n: int, block: int, align: int = 16) -> tuple[int, int]:
+    """(block, padded n) for a length-n axis: the block is ``block`` or,
+    for a short axis, n rounded up to ``align`` rows; n is padded to a
+    whole number of blocks."""
+    b = min(block, -(-n // align) * align)
+    return b, -(-n // b) * b
 
 
 def flash_attention(q, k, v, *, causal: bool = True, window=None,
                     block_q: int = 128, block_k: int = 128, interpret=None):
-    """q: (B, Sq, H, hd); k/v: (B, Sk, KV, hd) → (B, Sq, H, hd)."""
+    """q: (B, Sq, H, hd); k/v: (B, Sk, KV, hd) → (B, Sq, H, hd).
+
+    Ragged lengths are padded to whole blocks: padded keys are masked in
+    the kernel and padded query rows are sliced away."""
     interpret = _interpret_default() if interpret is None else interpret
-    B, Sq, H, hd = q.shape
-    Sk = k.shape[1]
-    bq, bk = min(block_q, Sq), min(block_k, Sk)
-    if Sq % bq or Sk % bk:
-        return ref.flash_attention_ref(q, k, v, causal=causal, window=window)
+    Sq, Sk = q.shape[1], k.shape[1]
+    bq, Sqp = _blocks(Sq, block_q)
+    bk, Skp = _blocks(Sk, block_k)
     qt = jnp.swapaxes(q, 1, 2)          # (B, H, Sq, hd)
     kt = jnp.swapaxes(k, 1, 2)          # (B, KV, Sk, hd)
     vt = jnp.swapaxes(v, 1, 2)
-    out = flash_attention_bhsd(qt, kt, vt, causal=causal, window=window,
-                               block_q=bq, block_k=bk, interpret=interpret)
-    return jnp.swapaxes(out, 1, 2)
+    pad = ((0, 0), (0, 0), (0, Skp - Sk), (0, 0))
+    out = flash_attention_bhsd(
+        jnp.pad(qt, ((0, 0), (0, 0), (0, Sqp - Sq), (0, 0))),
+        jnp.pad(kt, pad), jnp.pad(vt, pad), causal=causal, window=window,
+        block_q=bq, block_k=bk, kv_len=Sk, interpret=interpret)
+    return jnp.swapaxes(out[:, :, :Sq], 1, 2)
 
 
 def decode_attention(q, k_cache, v_cache, index, *, block_k: int = 512,
@@ -49,19 +68,17 @@ def decode_attention(q, k_cache, v_cache, index, *, block_k: int = 512,
     """q: (B, 1, H, hd); caches: (B, Smax, KV, hd) → (B, 1, H, hd).
 
     ``index`` is a scalar or a (B,) per-row position vector — both dispatch
-    to the same split-K kernel (the scalar broadcasts); only a ragged Smax
-    (not divisible by any block) falls back to the jnp reference."""
+    to the same split-K kernel (the scalar broadcasts).  An Smax that is
+    not a multiple of ``block_k`` ends in a partial block the kernel
+    masks."""
     interpret = _interpret_default() if interpret is None else interpret
     B, _, H, hd = q.shape
-    Smax, KV = k_cache.shape[1], k_cache.shape[2]
-    bk = min(block_k, Smax)
-    if Smax % bk:
-        return ref.decode_attention_ref(q, k_cache, v_cache, index)
+    KV = k_cache.shape[2]
     G = H // KV
     qt = q[:, 0].reshape(B, KV, G, hd)  # head h = kv·G + g, as in sdpa_ref
     kt = jnp.swapaxes(k_cache, 1, 2)    # (B, KV, Smax, hd)
     vt = jnp.swapaxes(v_cache, 1, 2)
-    out = decode_attention_bkgd(qt, kt, vt, index, block_k=bk,
+    out = decode_attention_bkgd(qt, kt, vt, index, block_k=block_k,
                                 interpret=interpret)
     return out.reshape(B, 1, H, hd)
 
@@ -109,8 +126,9 @@ def fused_sample(logits, seed, rid, pos, temperature, *, top_k: int = 0,
     with the host ``sampling.sample_token``) → (B,) int32 tokens.
 
     ``top_k`` is static per call (0 = full vocabulary); ``top_k > 0``
-    needs a per-row k-th order statistic, which the kernel doesn't tile —
-    it dispatches to the jnp reference, still entirely on device."""
+    needs a per-row k-th order statistic (a sort), which the kernel does
+    not do — it dispatches to the jnp reference, still entirely on
+    device."""
     interpret = _interpret_default() if interpret is None else interpret
     if top_k > 0:
         return ref.fused_sample_ref(logits, seed, rid, pos, temperature,
@@ -121,13 +139,18 @@ def fused_sample(logits, seed, rid, pos, temperature, *, top_k: int = 0,
 
 def ssm_scan(x, dt, A, B, C, *, chunk: int = 128, interpret=None):
     """SSD scan — x: (Bsz, L, H, hd); dt: (Bsz, L, H); A: (H,);
-    B/C: (Bsz, L, H, N) → y (Bsz, L, H, hd) fp32."""
+    B/C: (Bsz, L, H, N) → y (Bsz, L, H, hd) fp32.
+
+    A ragged L is padded at the end with dt = 0 steps (no decay, no
+    input), which leave every real step's output unchanged."""
     interpret = _interpret_default() if interpret is None else interpret
     L = x.shape[1]
-    T = min(chunk, L)
-    if L % T:
-        return ref.ssm_scan_ref(x, dt, A, B, C)
-    y = ssm_scan_ssd(x.astype(jnp.float32), dt.astype(jnp.float32), A,
-                     B.astype(jnp.float32), C.astype(jnp.float32),
-                     chunk=T, interpret=interpret)
-    return y
+    T, Lp = _blocks(L, chunk, align=8)
+
+    def pad(a):
+        a = a.astype(jnp.float32)
+        return jnp.pad(a, ((0, 0), (0, Lp - L)) + ((0, 0),) * (a.ndim - 2))
+
+    y = ssm_scan_ssd(pad(x), pad(dt), A, pad(B), pad(C), chunk=T,
+                     interpret=interpret)
+    return y[:, :L]

@@ -24,49 +24,60 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, o_ref, state_ref, *,
-                T: int):
+def _ssd_kernel(x_ref, dtr_ref, dtc_ref, a_ref, b_ref, c_ref, o_ref,
+                state_ref, *, T: int):
     ci = pl.program_id(2)
 
     @pl.when(ci == 0)
     def _init():
         state_ref[...] = jnp.zeros_like(state_ref)
 
-    x = x_ref[0, :, 0, :].astype(jnp.float32)        # (T, hd)
-    dt = dt_ref[0, :, 0].astype(jnp.float32)         # (T,)
-    A = a_ref[0, 0]                                  # scalar (negative)
-    Bm = b_ref[0, :, 0, :].astype(jnp.float32)       # (T, N)
-    Cm = c_ref[0, :, 0, :].astype(jnp.float32)       # (T, N)
+    x = x_ref[0, 0].astype(jnp.float32)              # (T, hd)
+    A = a_ref[0]                                     # (1, 1), negative
+    dt_r = dtr_ref[0, 0].astype(jnp.float32)         # (1, T)
+    dt_c = dtc_ref[0, 0].astype(jnp.float32)         # (T, 1)
+    lA_r, lA_c = dt_r * A, dt_c * A                  # log-decay ≤ 0
+    Bm = b_ref[0, 0].astype(jnp.float32)             # (T, N)
+    Cm = c_ref[0, 0].astype(jnp.float32)             # (T, N)
 
-    lA = dt * A                                      # (T,) log-decay ≤ 0
-    P = jnp.cumsum(lA)                               # inclusive prefix
+    # inclusive prefix sums of the log-decay, as a column and as a row —
+    # masked reductions over the (T, T) lower triangle
+    ii = jax.lax.broadcasted_iota(jnp.int32, (T, T), 0)
+    jj = jax.lax.broadcasted_iota(jnp.int32, (T, T), 1)
+    low = ii >= jj
+    P_c = jnp.sum(jnp.where(low, lA_r, 0.0), axis=1, keepdims=True)  # (T, 1)
+    P_r = jnp.sum(jnp.where(ii <= jj, lA_c, 0.0), axis=0,
+                  keepdims=True)                                     # (1, T)
+    P_last = jnp.sum(lA_c, axis=0, keepdims=True)                    # (1, 1)
 
     # intra-chunk quadratic form
     S = jax.lax.dot_general(Cm, Bm, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)   # (T, T)
-    ii = jax.lax.broadcasted_iota(jnp.int32, (T, T), 0)
-    jj = jax.lax.broadcasted_iota(jnp.int32, (T, T), 1)
-    Lmat = jnp.where(ii >= jj, jnp.exp(P[:, None] - P[None, :]), 0.0)
-    M = S * Lmat * dt[None, :]
+    Lmat = jnp.where(low, jnp.exp(P_c - P_r), 0.0)
+    M = S * Lmat * dt_r
     y = jax.lax.dot(M, x, preferred_element_type=jnp.float32)     # (T, hd)
 
     # inter-chunk contribution from the carried state (N, hd)
     state = state_ref[...]
-    y += jax.lax.dot(Cm * jnp.exp(P)[:, None], state,
+    y += jax.lax.dot(Cm * jnp.exp(P_c), state,
                      preferred_element_type=jnp.float32)
 
     # state update: decay full chunk + accumulate inputs
-    w = (dt * jnp.exp(P[T - 1] - P))[:, None] * x                 # (T, hd)
-    state_ref[...] = jnp.exp(P[T - 1]) * state + jax.lax.dot_general(
+    w = (dt_c * jnp.exp(P_last - P_c)) * x                        # (T, hd)
+    state_ref[...] = jnp.exp(P_last) * state + jax.lax.dot_general(
         Bm, w, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)                       # (N, hd)
 
-    o_ref[...] = y.astype(o_ref.dtype)[None, :, None, :]
+    o_ref[...] = y.astype(o_ref.dtype)[None, None]
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def ssm_scan_ssd(x, dt, A, B, C, *, chunk: int = 128, interpret: bool = False):
-    """x: (Bsz, L, H, hd); dt: (Bsz, L, H); A: (H,); B/C: (Bsz, L, H, N)."""
+    """x: (Bsz, L, H, hd); dt: (Bsz, L, H); A: (H,); B/C: (Bsz, L, H, N).
+
+    The kernel works head-major — x/B/C as (Bsz, H, L, ·) and dt both as a
+    row (Bsz, H, 1, L) and a column (Bsz, H, L, 1) — so every block's last
+    two dims are a (T, ·) tile or the array's full extent."""
     Bsz, L, H, hd = x.shape
     N = B.shape[-1]
     T = min(chunk, L)
@@ -74,19 +85,26 @@ def ssm_scan_ssd(x, dt, A, B, C, *, chunk: int = 128, interpret: bool = False):
     nc = L // T
     grid = (Bsz, H, nc)
 
+    def hm(a):
+        return jnp.swapaxes(a, 1, 2)                 # (Bsz, H, L, ·)
+
+    dth = jnp.swapaxes(dt, 1, 2)                     # (Bsz, H, L)
     kernel = functools.partial(_ssd_kernel, T=T)
-    return pl.pallas_call(
+    y = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, T, 1, hd), lambda b, h, c: (b, c, h, 0)),
-            pl.BlockSpec((1, T, 1), lambda b, h, c: (b, c, h)),
-            pl.BlockSpec((1, 1), lambda b, h, c: (h, 0)),
-            pl.BlockSpec((1, T, 1, N), lambda b, h, c: (b, c, h, 0)),
-            pl.BlockSpec((1, T, 1, N), lambda b, h, c: (b, c, h, 0)),
+            pl.BlockSpec((1, 1, T, hd), lambda b, h, c: (b, h, c, 0)),
+            pl.BlockSpec((1, 1, 1, T), lambda b, h, c: (b, h, 0, c)),
+            pl.BlockSpec((1, 1, T, 1), lambda b, h, c: (b, h, c, 0)),
+            pl.BlockSpec((1, 1, 1), lambda b, h, c: (h, 0, 0)),
+            pl.BlockSpec((1, 1, T, N), lambda b, h, c: (b, h, c, 0)),
+            pl.BlockSpec((1, 1, T, N), lambda b, h, c: (b, h, c, 0)),
         ],
-        out_specs=pl.BlockSpec((1, T, 1, hd), lambda b, h, c: (b, c, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((Bsz, L, H, hd), x.dtype),
+        out_specs=pl.BlockSpec((1, 1, T, hd), lambda b, h, c: (b, h, c, 0)),
+        out_shape=jax.ShapeDtypeStruct((Bsz, H, L, hd), x.dtype),
         scratch_shapes=[pltpu.VMEM((N, hd), jnp.float32)],
         interpret=interpret,
-    )(x, dt, A.reshape(H, 1).astype(jnp.float32), B, C)
+    )(hm(x), dth[:, :, None, :], dth[..., None],
+      A.reshape(H, 1, 1).astype(jnp.float32), hm(B), hm(C))
+    return hm(y)

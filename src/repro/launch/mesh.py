@@ -37,13 +37,19 @@ import jax
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape, axes):
     """Elastic variant: any (shape, axes) pair — used by launch/elastic.py to
-    re-mesh after node loss/gain and by tests for small device counts."""
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    re-mesh after node loss/gain and by tests for small device counts.
+
+    Axes are Auto: ``constrain`` and ``shard_map`` place arrays through
+    sharding constraints, which Explicit axes (``jax.make_mesh``'s default
+    since JAX 0.7) reject."""
+    from jax.sharding import AxisType
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def mesh_chips(mesh) -> int:

@@ -7,8 +7,16 @@ compatibility.  Per-request latency (p50/p95), throughput, and slot
 utilization are reported — the same metrics the paper's monitoring stream
 consumes (core/monitoring).
 
-Runnable at CPU scale:  PYTHONPATH=src python -m repro.launch.serve \
-    --arch qwen2.5-3b --smoke --requests 24 --max-seq 96
+Smoke size on the CPU (jnp reference path):
+
+    PYTHONPATH=src python -m repro.launch.serve \
+        --arch qwen2.5-3b --smoke --requests 24 --max-seq 96
+
+Full width on a TPU — bf16 weights and the Pallas kernels; without
+``--smoke`` the run fails unless JAX holds a TPU:
+
+    PYTHONPATH=src python -m repro.launch.serve --arch qwen2.5-3b \
+        --pallas --slots 8 --max-seq 2048 --prompt-len 200 --gen-len 32
 """
 from __future__ import annotations
 
@@ -19,6 +27,8 @@ import time
 import numpy as np
 
 from repro.configs import get_config, get_smoke_config
+from repro.launch.runtime import require_platform, setup_compile_cache
+from repro.models.config import DTYPES
 from repro.serving import SamplingParams, ServingEngine, synthetic_requests
 from repro.serving.slots import write_slot as _write_slot  # noqa: F401 (compat)
 from repro.sim.serving import WorkloadSpec
@@ -41,9 +51,23 @@ def main(argv=None):
     ap.add_argument("--top-k", type=int, default=0)
     ap.add_argument("--arrival-rps", type=float, default=100.0)
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--param-dtype", choices=sorted(DTYPES), default=None,
+                    help="weight dtype (default: bfloat16 at full width, "
+                         "the config's own with --smoke)")
+    ap.add_argument("--pallas", action="store_true",
+                    help="Pallas kernels (compiled on a TPU, interpreted "
+                         "on the CPU) instead of the jnp reference")
     args = ap.parse_args(argv)
 
-    cfg = (get_smoke_config if args.smoke else get_config)(args.arch)
+    setup_compile_cache()
+    if not args.smoke:
+        require_platform("tpu")
+    overrides = {"use_pallas": args.pallas}
+    param_dtype = args.param_dtype or (None if args.smoke else "bfloat16")
+    if param_dtype:
+        overrides["param_dtype"] = param_dtype
+    cfg = (get_smoke_config if args.smoke else get_config)(args.arch,
+                                                          **overrides)
     eng = ServingEngine(cfg, slots=args.slots, max_seq=args.max_seq,
                         seed=args.seed, prefill_chunk=args.prefill_chunk)
     rng = np.random.default_rng(args.seed)

@@ -175,6 +175,10 @@ class ChaosProxy:
             except OSError:
                 client.close()
                 continue
+            # the deadline bounds the connect only: a pump that timed out
+            # reading a slow reply (a worker's first init compiles) would
+            # sever a stream the fault plan never asked to cut
+            server.settimeout(None)
             with self._lock:
                 self._socks = [client, server]
 

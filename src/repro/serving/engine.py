@@ -66,10 +66,14 @@ class EngineCore:
     """Model params + jitted step functions, shared by all replicas of one
     deployment — N engines reuse one compile and one weight copy."""
 
-    def __init__(self, cfg, max_seq: int, *, seed: int = 0):
+    def __init__(self, cfg, max_seq: int, *, seed: int = 0, params=None):
+        """``params`` reuses weights already on the device (e.g. one weight
+        copy behind both the Pallas and the jnp-reference configuration);
+        by default they are generated from ``seed``."""
         self.cfg = cfg
         self.max_seq = max_seq
-        params, _ = LM.init(jax.random.PRNGKey(seed), cfg)
+        if params is None:
+            params, _ = LM.init(jax.random.PRNGKey(seed), cfg)
         self.params = params
         self.prefill = jax.jit(make_prefill_step(cfg, max_seq))
         self.decode = jax.jit(make_decode_step(cfg), donate_argnums=(2,))

@@ -75,6 +75,7 @@ from typing import Protocol, runtime_checkable
 import numpy as np
 
 from repro.core.monitoring.collector import ReplicaReport
+from repro.launch.runtime import PlatformError
 from repro.serving.engine import EngineCore, ServingEngine, validate_request
 from repro.serving.fleet import spawn_worker, worker_env
 from repro.serving.scheduler import Request, validate_tier
@@ -278,6 +279,39 @@ def _axes_leaf(x) -> bool:
                                         for a in x)
 
 
+def mesh_core(cfg, max_seq: int, mesh, *, seed: int = 0) -> EngineCore:
+    """An EngineCore whose weights sit replicated on every device of
+    ``mesh`` — placed once, instead of being re-broadcast from the default
+    device by every sharded decode call — with ``make_sharded_prefill`` as
+    its prefill."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    core = EngineCore(cfg, max_seq, seed=seed)
+    core.params = jax.device_put(core.params,
+                                 NamedSharding(mesh, PartitionSpec()))
+    core.prefill = make_sharded_prefill(cfg, mesh, max_seq)
+    return core
+
+
+def make_sharded_prefill(cfg, mesh, max_seq: int):
+    """Batch-1 prefill over mesh-replicated weights: under ``shard_map``
+    with every operand replicated, each device computes the same prefill.
+    Left to jit, the replicated weights would make XLA partition the
+    program, and Mosaic kernels (the flash prefill) cannot be partitioned
+    automatically."""
+    import jax
+    from jax.sharding import PartitionSpec
+
+    from repro.models.steps import make_prefill_step
+    from repro.sharding import shard_map
+
+    rep = PartitionSpec()
+    return jax.jit(shard_map(make_prefill_step(cfg, max_seq), mesh=mesh,
+                             in_specs=(rep, rep), out_specs=(rep, rep),
+                             check_vma=False))
+
+
 def make_sharded_decode(cfg, mesh, slots: int, max_seq: int, *,
                         pool: str = "dense", block_size: int | None = None,
                         num_blocks: int | None = None):
@@ -366,6 +400,8 @@ class ShardedReplica(InProcessReplica):
         if slots % n_dev != 0:
             raise ValueError(f"slots ({slots}) must divide evenly over the "
                              f"mesh ({n_dev} devices)")
+        if core is None:
+            core = mesh_core(cfg, max_seq, mesh, seed=seed)
         # paged allocator partitions track the mesh: slot s draws blocks
         # only from its own shard's contiguous block range, so the sharded
         # decode body's global→local block-id fold stays exact
@@ -424,7 +460,7 @@ class SocketReplica:
                  batch_submits: bool = True, pool: str = "dense",
                  block_size: int | None = None,
                  num_blocks: int | None = None, spec_k: int = 0,
-                 spec_ngram: int = 3):
+                 spec_ngram: int = 3, platform: str | None = None):
         self.cfg = cfg
         self.slots = slots
         self.max_seq = max_seq
@@ -465,7 +501,8 @@ class SocketReplica:
                    "prefill_chunk": prefill_chunk,
                    "replica_id": replica_id, "pool": pool,
                    "block_size": block_size, "num_blocks": num_blocks,
-                   "spec_k": spec_k, "spec_ngram": spec_ngram},
+                   "spec_k": spec_k, "spec_ngram": spec_ngram,
+                   "platform": platform},
                   timeout=init_timeout_s)
 
     # ------------------------------------------------------------- plumbing
@@ -527,6 +564,13 @@ class SocketReplica:
                 # this stub never owned the peer, so fail typed and final
                 self._mark_failed()
                 raise WorkerBusyError(
+                    f"replica {self.replica_id}: {reply['error']}")
+            if reply.get("etype") == "PlatformError":
+                # the worker came up off the platform it was spawned for
+                # (a chip host whose child fell back to the CPU): it must
+                # never serve, so the replica is final — typed, not retried
+                self._mark_failed()
+                raise PlatformError(
                     f"replica {self.replica_id}: {reply['error']}")
             if reply.get("etype") == "PodDesyncError":
                 # a pod whose ranks diverged retires as a unit — same
@@ -840,7 +884,7 @@ class ProcessReplica(SocketReplica):
                  batch_submits: bool = True, pool: str = "dense",
                  block_size: int | None = None,
                  num_blocks: int | None = None, spec_k: int = 0,
-                 spec_ngram: int = 3):
+                 spec_ngram: int = 3, platform: str | None = None):
         parent_sock, child_sock = socket.socketpair()
         child_sock.set_inheritable(True)
         proc = subprocess.Popen(
@@ -855,7 +899,8 @@ class ProcessReplica(SocketReplica):
                          init_timeout_s=init_timeout_s,
                          batch_submits=batch_submits, pool=pool,
                          block_size=block_size, num_blocks=num_blocks,
-                         spec_k=spec_k, spec_ngram=spec_ngram)
+                         spec_k=spec_k, spec_ngram=spec_ngram,
+                         platform=platform)
 
 
 class TcpReplica(SocketReplica):
@@ -877,7 +922,7 @@ class TcpReplica(SocketReplica):
                  batch_submits: bool = True, pool: str = "dense",
                  block_size: int | None = None,
                  num_blocks: int | None = None, spec_k: int = 0,
-                 spec_ngram: int = 3):
+                 spec_ngram: int = 3, platform: str | None = None):
         proc = None
         if addr is None:
             addr, proc = spawn_worker()
@@ -894,8 +939,9 @@ class TcpReplica(SocketReplica):
                              init_timeout_s=init_timeout_s,
                              batch_submits=batch_submits, pool=pool,
                              block_size=block_size, num_blocks=num_blocks,
-                             spec_k=spec_k, spec_ngram=spec_ngram)
-        except TransportError:
+                             spec_k=spec_k, spec_ngram=spec_ngram,
+                             platform=platform)
+        except (TransportError, PlatformError):
             # dial or handshake died before the stub owned the worker's
             # lifetime — do not leak a locally-spawned process
             if proc is not None and proc.poll() is None:
@@ -928,7 +974,7 @@ class DistributedPodReplica(TcpReplica):
                  batch_submits: bool = True, pool: str = "dense",
                  block_size: int | None = None,
                  num_blocks: int | None = None, spec_k: int = 0,
-                 spec_ngram: int = 3):
+                 spec_ngram: int = 3, platform: str | None = None):
         from repro.serving.fleet import launch_pod
 
         self.pod_size = int(pod_size)
@@ -945,7 +991,8 @@ class DistributedPodReplica(TcpReplica):
                              connect_timeout_s=connect_timeout_s,
                              batch_submits=batch_submits, pool=pool,
                              block_size=block_size, num_blocks=num_blocks,
-                             spec_k=spec_k, spec_ngram=spec_ngram)
+                             spec_k=spec_k, spec_ngram=spec_ngram,
+                             platform=platform)
         except Exception:
             if self._pod_handle is not None:
                 self._pod_handle.close()

@@ -180,7 +180,8 @@ class ReplicaRouter:
                       num_blocks: int | None = None, spec_k: int = 0,
                       spec_ngram: int = 3,
                       profile_fn=None, region_aware: bool = True,
-                      delay_fn=None) -> "ReplicaRouter":
+                      delay_fn=None,
+                      platform: str | None = None) -> "ReplicaRouter":
         """Build the fleet for one of the five replica topologies.
 
         inproc  — replicas share one EngineCore (no re-init / re-jit).
@@ -217,6 +218,10 @@ class ReplicaRouter:
         accepts the knobs but serves the plain path (its decode step is
         compiled for single-position ticks).
 
+        ``platform`` (proc/tcp/pod) names the platform each worker must
+        come up on (``"tpu"`` on a chip host): a worker that finds another
+        fails its init with a typed ``PlatformError`` instead of serving.
+
         ``profile_fn(replica_id) -> ReplicaProfile`` (e.g. a
         serving/profiles.py FleetPlan) declares the fleet heterogeneous —
         cost/speed-aware routing, tier placement, preemptible semantics;
@@ -230,37 +235,37 @@ class ReplicaRouter:
         pool_kw = dict(pool=pool, block_size=block_size,
                        num_blocks=num_blocks, spec_k=spec_k,
                        spec_ngram=spec_ngram)
+        remote_kw = dict(pool_kw, batch_submits=batch_submits,
+                         platform=platform)
         if topology == "proc":
             from repro.serving.replica import ProcessReplica
 
             def factory(replica_id: int):
                 return ProcessReplica(cfg, slots=slots, max_seq=max_seq,
                                       seed=seed, prefill_chunk=prefill_chunk,
-                                      replica_id=replica_id,
-                                      batch_submits=batch_submits, **pool_kw)
+                                      replica_id=replica_id, **remote_kw)
         elif topology == "tcp":
             from repro.serving.replica import TcpReplica
             factory = _attach_factory(
                 TcpReplica, cfg, list(addrs or []), topology, slots=slots,
                 max_seq=max_seq, seed=seed, prefill_chunk=prefill_chunk,
-                batch_submits=batch_submits, **pool_kw)
+                **remote_kw)
         elif topology == "pod":
             from repro.serving.replica import DistributedPodReplica
             factory = _attach_factory(
                 DistributedPodReplica, cfg, list(addrs or []), topology,
                 slots=slots, max_seq=max_seq, seed=seed,
-                prefill_chunk=prefill_chunk, pod_size=pod_size,
-                batch_submits=batch_submits, **pool_kw)
+                prefill_chunk=prefill_chunk, pod_size=pod_size, **remote_kw)
         elif topology == "sharded":
             from repro.serving.replica import (
-                ShardedReplica, make_sharded_decode,
+                ShardedReplica, make_sharded_decode, mesh_core,
             )
             if mesh is None:
                 import jax
 
                 from repro.launch.mesh import make_mesh
                 mesh = make_mesh((len(jax.devices()),), ("data",))
-            core = EngineCore(cfg, max_seq, seed=seed)
+            core = mesh_core(cfg, max_seq, mesh, seed=seed)
             decode_fn = make_sharded_decode(cfg, mesh, slots, max_seq,
                                             pool=pool, block_size=block_size,
                                             num_blocks=num_blocks)

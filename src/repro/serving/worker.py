@@ -46,7 +46,10 @@ as a seq desync.
 Ops mirror the Replica protocol 1:1 (see serving/replica.py):
 
   attach    — session handshake: {"mode": "mutate" | "observe"}
-  init      — build the engine from an encoded ModelConfig (the handshake)
+  init      — build the engine from an encoded ModelConfig (the handshake);
+              with ``"platform"`` set, a worker whose JAX came up on
+              another platform replies a typed ``PlatformError`` and
+              serves nothing
   submit    — enqueue one request (validation errors bounce back typed)
   step      — one scheduling round; batched submits (``"submits": [...]``)
               are enqueued first, so one message per round replaces one per
@@ -287,6 +290,9 @@ def handle(engine, msg: dict, pod: PodRuntime | None = None):
         # mutator by construction, so the claim is always granted.
         return {"ok": True, "role": msg.get("mode", "mutate")}
     if op == "init":
+        if msg.get("platform"):
+            from repro.launch.runtime import require_platform
+            require_platform(msg["platform"])
         if pod is not None:
             return {"ok": True, "engine": pod.build_engine(msg)}
         from repro.serving.engine import ServingEngine
@@ -666,6 +672,8 @@ def main(argv=None) -> int:
                     help="head only: the non-head ranks' listen addresses, "
                          "rank-ordered")
     args = ap.parse_args(argv)
+    from repro.launch.runtime import setup_compile_cache
+    setup_compile_cache()
     pod = None
     if args.pod_rank is not None:
         if not args.listen:

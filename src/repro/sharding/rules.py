@@ -230,15 +230,9 @@ def current_ctx():
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """jax.shard_map across jax versions: new jax exposes it top-level with
-    ``check_vma``; older releases (≤0.4.x) only have
-    jax.experimental.shard_map with the equivalent ``check_rep`` flag."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma)
+    """``jax.shard_map`` with this repo's keyword defaults."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 def constrain(x, logical_axes: Sequence[str | None]):

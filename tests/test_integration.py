@@ -134,6 +134,26 @@ def test_serve_driver_end_to_end():
     assert rc == 0
 
 
+def test_serve_driver_pallas_bf16_smoke():
+    """The chip path's flags at smoke size: bf16 weights and the Pallas
+    kernels (interpreted on the CPU backend)."""
+    from repro.launch.serve import main
+    rc = main(["--arch", "qwen2.5-3b", "--smoke", "--pallas",
+               "--param-dtype", "bfloat16", "--requests", "3",
+               "--slots", "2", "--max-seq", "40", "--prompt-len", "9",
+               "--gen-len", "3", "--arrival-rps", "50"])
+    assert rc == 0
+
+
+def test_serve_driver_full_width_needs_a_tpu():
+    """Without --smoke the driver is on the chip path: on the CPU it must
+    fail before building the 3B-parameter model, not serve on the CPU."""
+    from repro.launch.runtime import PlatformError
+    from repro.launch.serve import main
+    with pytest.raises(PlatformError):
+        main(["--arch", "qwen2.5-3b", "--pallas"])
+
+
 def test_serving_engine_decode_matches_single_request():
     """Slot-batched decode must produce the same tokens as a fresh
     single-request engine for the same prompt (batching is transparent)."""

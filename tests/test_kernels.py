@@ -66,8 +66,8 @@ def test_flash_attention_bf16():
 
 
 def test_flash_attention_ragged_falls_back_to_ref():
-    # Sq=100 not divisible by any power-of-two block: wrapper must still be
-    # exact (it dispatches to the reference path).
+    # Sq=100 not divisible by any power-of-two block: the wrapper pads to a
+    # whole block and the kernel masks the padded keys — still exact.
     q, k, v = _qkv(jax.random.PRNGKey(2), 1, 100, 100, 4, 2, 32, jnp.float32)
     out = ops.flash_attention(q, k, v, causal=True, interpret=True)
     want = ref.flash_attention_ref(q, k, v, causal=True)
@@ -162,7 +162,7 @@ def test_ssm_scan_state_decay_property():
 
 
 def test_ssm_scan_ragged_falls_back():
-    B, L, H, hd, N = 1, 100, 2, 8, 4   # L % chunk != 0
+    B, L, H, hd, N = 1, 100, 2, 8, 4   # L % chunk != 0: padded with dt = 0
     key = jax.random.PRNGKey(13)
     x = jax.random.normal(key, (B, L, H, hd), jnp.float32)
     dt = jax.nn.softplus(jax.random.normal(jax.random.fold_in(key, 1), (B, L, H)))
