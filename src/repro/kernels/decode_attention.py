@@ -131,6 +131,7 @@ def decode_attention_bkgd(q, k_cache, v_cache, index, *, block_k: int = 512,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, G, hd), q.dtype),
         interpret=interpret,
+        name="decode_attention_bkgd",    # the kernel's name in a trace
     )(idx, q, k_cache, v_cache)
 
 

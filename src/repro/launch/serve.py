@@ -80,17 +80,22 @@ def main(argv=None):
                                   sampling=sampling)
 
     t0 = time.time()
+
+    def clock():
+        return time.time() - t0
+
     submitted = 0
     finished: list = []
     while len(finished) < args.requests:
-        now = time.time() - t0
+        now = clock()
         while submitted < args.requests and arrivals[submitted] <= now:
             eng.submit(requests[submitted], now=arrivals[submitted])
             submitted += 1
         if eng.idle:
             time.sleep(0.001)
             continue
-        finished.extend(eng.step(now=time.time() - t0))
+        # the clock itself: each request is stamped when its token lands
+        finished.extend(eng.step(now=clock))
 
     total = time.time() - t0
     lats = np.array(sorted(r.latency_s for r in finished))

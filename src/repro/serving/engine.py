@@ -38,6 +38,11 @@ from a naive batched decoder:
 
 The low-level admit()/tick() surface is kept compatible with the seed's
 launch/serve.py engine; submit()/step() add the queued-request lifecycle.
+
+The host loop records named spans (serving/spans.py) at each of its steps
+and counts, in ``EngineStats``, the tokens it emits and the bytes it pulls
+from the device, so a profiler trace shows where the host was while the
+device sat idle.
 """
 from __future__ import annotations
 
@@ -58,8 +63,20 @@ from repro.models.steps import (
 from repro.serving.draft import ngram_propose
 from repro.serving.scheduler import FCFSScheduler, Request
 from repro.serving.slots import make_pool
+from repro.serving.spans import span
 
 PHASE_FREE, PHASE_PREFILL, PHASE_DECODE = 0, 1, 2
+
+
+def _reading(now):
+    """One reading of the caller's clock: ``now()`` where the caller passed
+    the clock itself, else ``now``."""
+    return now() if callable(now) else now
+
+
+def _rid(req) -> int:
+    """The request id a span carries (-1 for a slot with no Request)."""
+    return req.rid if isinstance(req, Request) else -1
 
 
 class EngineCore:
@@ -95,6 +112,11 @@ class EngineStats:
         self.total_busy = 0.0
         self.total_spec_proposed = 0
         self.total_spec_accepted = 0
+        # host traffic: tokens appended to requests' outputs, and the bytes
+        # (and logits rows) the engine materialized from the device
+        self.total_emitted = 0
+        self.total_pulled_bytes = 0
+        self.total_rows_pulled = 0
         self.completed_by_tier: dict[str, int] = {}
         self.latencies_ms = deque(maxlen=4096)
         self.queue_depth = 0
@@ -109,6 +131,9 @@ class EngineStats:
         self._win_busy = 0.0
         self._win_spec_prop = 0
         self._win_spec_acc = 0
+        self._win_emitted = 0
+        self._win_pulled_bytes = 0
+        self._win_rows_pulled = 0
 
     def on_tick(self, busy_slots: int, slots: int, queue_depth: int):
         self.total_ticks += 1
@@ -136,6 +161,18 @@ class EngineStats:
         self._win_spec_prop += proposed
         self._win_spec_acc += accepted
 
+    def on_emit(self):
+        self.total_emitted += 1
+        self._win_emitted += 1
+
+    def on_pull(self, nbytes: int, rows: int = 0):
+        """One device array materialized on the host: its device bytes
+        (before any host cast), and how many of them are logits rows."""
+        self.total_pulled_bytes += nbytes
+        self._win_pulled_bytes += nbytes
+        self.total_rows_pulled += rows
+        self._win_rows_pulled += rows
+
     @property
     def slot_utilization(self) -> float:
         return self.total_busy / max(self.total_ticks, 1)
@@ -154,6 +191,9 @@ class EngineStats:
             "queue_depth": self.queue_depth,
             "spec_proposed": self._win_spec_prop,
             "spec_accepted": self._win_spec_acc,
+            "emitted_tokens": self._win_emitted,
+            "pulled_bytes": self._win_pulled_bytes,
+            "rows_pulled": self._win_rows_pulled,
         }
         self._reset_window()
         return out
@@ -255,7 +295,6 @@ class ServingEngine:
             and cfg.ssm is None and getattr(cfg, "hybrid", None) is None
             and not cfg.enc_dec and not cfg.attn_free
             and Attention.cache_len(cfg, max_seq) == max_seq)
-        self.logits_pulls = 0        # host (·, V) logits materializations
         self.scheduler = FCFSScheduler()
         self.draining = False
         self.stats = EngineStats()
@@ -285,41 +324,53 @@ class ServingEngine:
         return (int(self.active.sum()) + self.scheduler.depth) / max(
             self.slots, 1)
 
-    def step(self, now: float | None = None) -> list[Request]:
+    def step(self, now=None) -> list[Request]:
         """One scheduling round: FCFS admission into free slots, one decode
-        tick, completion + slot release.  Returns finished requests."""
+        tick, completion + slot release.  Returns finished requests.
+
+        ``now`` is the caller's clock: either the clock itself (a callable
+        returning seconds), read again when each stamped token is on the
+        host, or one reading of it, at which every stamp of the round is
+        taken (a virtual clock, on which a round takes no time).  ``None``
+        is the engine's own clock, ``time.monotonic``."""
         if now is None:
-            now = time.monotonic()
+            now = time.monotonic
         completed: list[Request] = []
-        if not self.draining:
-            free = [s for s in range(self.slots) if not self.active[s]]
-            while free and self.scheduler:
-                if self._paged:
-                    # head-of-line capacity gate: a paged pool can have free
-                    # SLOTS but no free BLOCKS (slots oversubscribe the
-                    # pool); admitting anyway would fault mid-decode, and
-                    # skipping ahead would break FCFS order
-                    head = self.scheduler.peek()
-                    if not self.pool.can_admit(
-                            free[0], np.asarray(head.prompt).reshape(-1),
-                            head.gen_len, extra=self._patch_key):
-                        break
-                req = self.scheduler.pop()
-                slot = free.pop(0)
-                req.t_admit = now
-                req.replica_id = self.replica_id
-                self.admit(slot, req.prompt, req.gen_len, request=req)
-                if self.phase[slot] == PHASE_DECODE:
-                    req.t_first_token = now      # prompt fit in one chunk
-        for slot in self.tick(now=now):
-            req = self.slot_owner.get(slot)
-            self.release_slot(slot)
-            if isinstance(req, Request):
-                req.t_done = now
-                self.stats.on_complete(req)
-                completed.append(req)
-        self.stats.on_tick(int(self.active.sum()), self.slots,
-                           self.scheduler.depth)
+        with span("serve.step"):
+            t_start = _reading(now)
+            if not self.draining:
+                free = [s for s in range(self.slots) if not self.active[s]]
+                while free and self.scheduler:
+                    if self._paged:
+                        # head-of-line capacity gate: a paged pool can have
+                        # free SLOTS but no free BLOCKS (slots oversubscribe
+                        # the pool); admitting anyway would fault
+                        # mid-decode, and skipping ahead would break FCFS
+                        head = self.scheduler.peek()
+                        if not self.pool.can_admit(
+                                free[0], np.asarray(head.prompt).reshape(-1),
+                                head.gen_len, extra=self._patch_key):
+                            break
+                    req = self.scheduler.pop()
+                    slot = free.pop(0)
+                    req.t_admit = t_start
+                    req.replica_id = self.replica_id
+                    self.admit(slot, req.prompt, req.gen_len, request=req)
+                    if self.phase[slot] == PHASE_DECODE:
+                        # prompt fit in one chunk: its first token is on
+                        # the host now
+                        req.t_first_token = _reading(now)
+            finished = self.tick(now=now)
+            t_done = _reading(now) if finished else None
+            for slot in finished:
+                req = self.slot_owner.get(slot)
+                self.release_slot(slot)
+                if isinstance(req, Request):
+                    req.t_done = t_done
+                    self.stats.on_complete(req)
+                    completed.append(req)
+            self.stats.on_tick(int(self.active.sum()), self.slots,
+                               self.scheduler.depth)
         return completed
 
     # ------------------------------------------------------------- slot API
@@ -331,6 +382,10 @@ class ServingEngine:
         pass ``frames`` (or carry them on the request): the encoder runs
         whole in the one-shot portion — cross K/V cover every frame and the
         decoder prompt tail can still stream through the decode tick."""
+        with span("serve.admit", rid=_rid(request), slot=slot):
+            self._admit(slot, prompt, gen_len, request, frames)
+
+    def _admit(self, slot, prompt, gen_len, request, frames):
         if self.active[slot]:
             raise ValueError(f"slot {slot} is still active")
         if frames is None and request is not None:
@@ -374,8 +429,10 @@ class ServingEngine:
         if self.cfg.enc_dec:
             inputs["frames"] = jnp.asarray(np.asarray(frames)[None],
                                            self.cfg.cdtype)
-        logits, cache1 = self.prefill(self.params, inputs)
-        self.pool.write(cache1, slot, index=c)
+        with span("serve.prefill"):
+            logits, cache1 = self.prefill(self.params, inputs)
+        with span("serve.pool_write"):
+            self.pool.write(cache1, slot, index=c)
         if self._paged:
             # blocks fully covered by the one-shot prefill are complete
             # prompt prefixes — publish them for future admissions to share
@@ -389,9 +446,14 @@ class ServingEngine:
         if request is not None:
             self.slot_owner[slot] = request
         if c == P:
-            row = np.asarray(logits[0, -1], np.float32)
-            tok = (request.sample(row) if request is not None
-                   else int(np.argmax(row)))
+            # the admission's row counts its bytes, not as a tick's pull
+            row = self._pull_row(logits, (0, -1), slot, rows=0)
+            if request is not None:
+                with span("serve.host_draw", rid=request.rid, slot=slot):
+                    tok = request.sample(row)
+                self.stats.on_emit()
+            else:
+                tok = int(np.argmax(row))
             self._tokens_host[slot] = tok
             self.phase[slot] = PHASE_DECODE
         else:
@@ -418,23 +480,41 @@ class ServingEngine:
         """
         if not self.active.any():
             return []
-        if self.decode is not self.core.decode:
+        with span("serve.tick"):
+            if self.decode is not self.core.decode:
+                return self._tick_legacy(now)
+            if self._spec_ok:
+                drafts, window_w = self._plan_window()
+                if window_w >= 2:
+                    return self._tick_verify(drafts, window_w, now)
+            return self._tick_fused(now)
+
+    # -------------------------------------------------- shared tick plumbing
+
+    def _tick_legacy(self, now) -> list[int]:
+        """A replaced decode step: bulk-pull (slots, V) rows, host argmax."""
+        with span("serve.decode_dispatch"):
             if self._tokens_dirty:
                 self._stage_tokens()
             logits, cache = self.decode(self.params, self.tokens,
                                         self.pool.cache)
             self.pool.cache = cache
-            rows = np.asarray(logits[:, 0], np.float32)     # (slots, V)
-            self.logits_pulls += 1
-            toks = np.argmax(rows, axis=1).astype(np.int32)
-            return self._advance(toks, lambda s: rows[s], now)
-        if self._spec_ok:
-            drafts, window_w = self._plan_window()
-            if window_w >= 2:
-                return self._tick_verify(drafts, window_w, now)
-        return self._tick_fused(now)
+        with span("serve.decode_wait"):
+            dev = logits[:, 0]
+            rows = np.asarray(dev, np.float32)              # (slots, V)
+        self.stats.on_pull(dev.nbytes, rows=1)
+        toks = np.argmax(rows, axis=1).astype(np.int32)
+        return self._advance(toks, lambda s: rows[s], now)
 
-    # -------------------------------------------------- shared tick plumbing
+    def _pull_row(self, logits, index, slot: int, rows: int = 1):
+        """One logits row, ``logits[index]``, to the host as float32; its
+        device bytes are counted, and ``rows`` logits pulls."""
+        with span("serve.row_pull", rid=_rid(self.slot_owner.get(slot)),
+                  slot=slot):
+            dev = logits[index]
+            row = np.asarray(dev, np.float32)
+        self.stats.on_pull(dev.nbytes, rows=rows)
+        return row
 
     def _stage_tokens(self):
         """Materialize the device copy of every slot's next input token."""
@@ -445,11 +525,16 @@ class ServingEngine:
         """One sampled token for a slot, device-first: greedy rows take the
         device-sampled token (bit-equal to host argmax), temperature rows
         pull their one logits row and keep their stateful host RNG."""
-        if isinstance(req, Request) and req.sampling.temperature > 0.0:
-            return req.sample(fetch_row(slot))
-        tok = int(tok_dev)
-        if isinstance(req, Request):
+        if not isinstance(req, Request):
+            return int(tok_dev)
+        if req.sampling.temperature > 0.0:
+            row = fetch_row(slot)
+            with span("serve.host_draw", rid=req.rid, slot=slot):
+                tok = req.sample(row)
+        else:
+            tok = int(tok_dev)
             req.tokens_out.append(tok)
+        self.stats.on_emit()
         return tok
 
     def _advance(self, toks_host, fetch_row, now) -> list[int]:
@@ -480,7 +565,7 @@ class ServingEngine:
                     self.phase[slot] = PHASE_DECODE
                     if (isinstance(req, Request) and req.t_first_token is None
                             and now is not None):
-                        req.t_first_token = now
+                        req.t_first_token = _reading(now)
             else:
                 self.remaining[slot] -= 1
                 if self.remaining[slot] <= 0:
@@ -497,30 +582,29 @@ class ServingEngine:
         kernel draws from stateless (seed, rid, pos) counters per row, and a
         greedy tick pulls (slots,) int32 tokens — zero host logits traffic."""
         B = self.slots
-        seed = np.zeros(B, np.int32)
-        rid = np.zeros(B, np.int32)
-        pos = np.zeros(B, np.int32)
-        temp = np.zeros(B, np.float32)
-        for slot, req in self.slot_owner.items():
-            if isinstance(req, Request):
-                seed[slot] = req.sampling.seed
-                rid[slot] = req.rid
-                pos[slot] = len(req.tokens_out)
-                temp[slot] = req.sampling.temperature
-        if self._tokens_dirty:
-            self._stage_tokens()
-        toks, logits, cache = self.core.fused_decode(
-            self.params, self.tokens, self.pool.cache,
-            jnp.asarray(seed), jnp.asarray(rid), jnp.asarray(pos),
-            jnp.asarray(temp))
-        self.pool.cache = cache
-        toks_host = np.asarray(toks)                    # (slots,) int32
-
-        def fetch_row(s):
-            self.logits_pulls += 1
-            return np.asarray(logits[s, 0], np.float32)
-
-        return self._advance(toks_host, fetch_row, now)
+        with span("serve.decode_dispatch"):
+            seed = np.zeros(B, np.int32)
+            rid = np.zeros(B, np.int32)
+            pos = np.zeros(B, np.int32)
+            temp = np.zeros(B, np.float32)
+            for slot, req in self.slot_owner.items():
+                if isinstance(req, Request):
+                    seed[slot] = req.sampling.seed
+                    rid[slot] = req.rid
+                    pos[slot] = len(req.tokens_out)
+                    temp[slot] = req.sampling.temperature
+            if self._tokens_dirty:
+                self._stage_tokens()
+            toks, logits, cache = self.core.fused_decode(
+                self.params, self.tokens, self.pool.cache,
+                jnp.asarray(seed), jnp.asarray(rid), jnp.asarray(pos),
+                jnp.asarray(temp))
+            self.pool.cache = cache
+        with span("serve.decode_wait"):
+            toks_host = np.asarray(toks)                # (slots,) int32
+        self.stats.on_pull(toks.nbytes)
+        return self._advance(
+            toks_host, lambda s: self._pull_row(logits, (s, 0), s), now)
 
     # ------------------------------------------------------- speculative path
 
@@ -573,27 +657,30 @@ class ServingEngine:
         relies on.
         """
         B = self.slots
-        window = np.zeros((B, W), np.int32)
-        window[:, 0] = self._tokens_host
-        n_extra = np.zeros(B, np.int64)      # prompt tokens fed in lanes 1..
-        n_draft = np.zeros(B, np.int64)      # draft tokens staged in lanes 1..
-        for slot in np.nonzero(self.active)[0]:
-            slot = int(slot)
-            if self.phase[slot] == PHASE_PREFILL:
-                prompt = self._prompt[slot]
-                m = min(W - 1, len(prompt) - int(self._fed[slot]))
-                if m > 0:
-                    lo = int(self._fed[slot])
-                    window[slot, 1:1 + m] = prompt[lo:lo + m]
-                    n_extra[slot] = m
-            elif slot in drafts:
-                d = drafts[slot][:W - 1]
-                window[slot, 1:1 + len(d)] = d
-                n_draft[slot] = len(d)
-        toks, logits, cache = self.core.verify(
-            self.params, jnp.asarray(window), self.pool.cache)
-        self.pool.cache = cache
-        toks_host = np.asarray(toks)                    # (slots, W) int32
+        with span("serve.decode_dispatch"):
+            window = np.zeros((B, W), np.int32)
+            window[:, 0] = self._tokens_host
+            n_extra = np.zeros(B, np.int64)  # prompt tokens fed in lanes 1..
+            n_draft = np.zeros(B, np.int64)  # draft tokens staged in lanes 1..
+            for slot in np.nonzero(self.active)[0]:
+                slot = int(slot)
+                if self.phase[slot] == PHASE_PREFILL:
+                    prompt = self._prompt[slot]
+                    m = min(W - 1, len(prompt) - int(self._fed[slot]))
+                    if m > 0:
+                        lo = int(self._fed[slot])
+                        window[slot, 1:1 + m] = prompt[lo:lo + m]
+                        n_extra[slot] = m
+                elif slot in drafts:
+                    d = drafts[slot][:W - 1]
+                    window[slot, 1:1 + len(d)] = d
+                    n_draft[slot] = len(d)
+            toks, logits, cache = self.core.verify(
+                self.params, jnp.asarray(window), self.pool.cache)
+            self.pool.cache = cache
+        with span("serve.decode_wait"):
+            toks_host = np.asarray(toks)                # (slots, W) int32
+        self.stats.on_pull(toks.nbytes)
 
         done: list[int] = []
         for slot in np.nonzero(self.active)[0]:
@@ -601,8 +688,7 @@ class ServingEngine:
             req = self.slot_owner.get(slot)
 
             def fetch_row(lane, slot=slot):
-                self.logits_pulls += 1
-                return np.asarray(logits[slot, lane], np.float32)
+                return self._pull_row(logits, (slot, lane), slot)
 
             if self.phase[slot] == PHASE_PREFILL:
                 done.extend(self._advance_prefill_window(
@@ -647,7 +733,7 @@ class ServingEngine:
             self.phase[slot] = PHASE_DECODE
             if (isinstance(req, Request) and req.t_first_token is None
                     and now is not None):
-                req.t_first_token = now
+                req.t_first_token = _reading(now)
         return []
 
     def _advance_decode_window(self, slot, req, window, m, toks_host,
@@ -739,7 +825,9 @@ class ServingEngine:
             "prompt_tokens": int(self.prompt_tokens),
             "spec_proposed": int(self.stats.total_spec_proposed),
             "spec_accepted": int(self.stats.total_spec_accepted),
-            "logits_pulls": int(self.logits_pulls),
+            "logits_pulls": int(self.stats.total_rows_pulled),
+            "emitted_tokens": int(self.stats.total_emitted),
+            "pulled_bytes": int(self.stats.total_pulled_bytes),
         }
         if self._paged:
             out["prefix_hits"] = int(self.pool.n_prefix_hits)
@@ -748,6 +836,12 @@ class ServingEngine:
         return out
 
     # ------------------------------------------------------------- compat
+
+    @property
+    def logits_pulls(self) -> int:
+        """Logits pulls by decode ticks: one per sampled row, one per bulk
+        (slots, V) pull of a replaced decode step."""
+        return self.stats.total_rows_pulled
 
     @property
     def cache(self):

@@ -9,6 +9,7 @@ may load the TPU library, and under several pytest workers only the worker
 given this file loads it.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -128,18 +129,25 @@ def _full_width_structs(sharding_of):
     return cfg, place(params), place(cache)
 
 
-def test_full_width_fused_decode_step_fits_one_chip(one_chip, monkeypatch):
-    """The serving tick at Qwen2.5-3B's published widths with bf16 weights:
-    8 slots over a 2048-position dense pool.  The step's own code takes the
-    CPU branch here (interpreted kernels), so the test steers it to the
-    compiled kernels."""
-    monkeypatch.setattr(ops, "_interpret_default", lambda: False)
-    cfg, params, cache = _full_width_structs(lambda x: one_chip)
-    vec = _s(one_chip, (B,), I32)
-    compiled = jax.jit(make_fused_decode_step(cfg), donate_argnums=(2,)
-                       ).lower(params, _s(one_chip, (B, 1), I32), cache,
-                               vec, vec, vec,
-                               _s(one_chip, (B,), F32)).compile()
+@pytest.fixture(scope="module")
+def full_width_step(one_chip):
+    """The serving tick at Qwen2.5-3B's published widths with bf16 weights,
+    compiled: 8 slots over a 2048-position dense pool.  The step's own code
+    takes the CPU branch here (interpreted kernels), so the fixture steers
+    it to the compiled kernels.  → (compiled, params structs)."""
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(ops, "_interpret_default", lambda: False)
+        cfg, params, cache = _full_width_structs(lambda x: one_chip)
+        vec = _s(one_chip, (B,), I32)
+        compiled = jax.jit(make_fused_decode_step(cfg), donate_argnums=(2,)
+                           ).lower(params, _s(one_chip, (B, 1), I32), cache,
+                                   vec, vec, vec,
+                                   _s(one_chip, (B,), F32)).compile()
+    return compiled, params
+
+
+def test_full_width_fused_decode_step_fits_one_chip(full_width_step):
+    compiled, params = full_width_step
     assert "tpu_custom_call" in compiled.as_text()
     mem = compiled.memory_analysis()
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
@@ -147,6 +155,18 @@ def test_full_width_fused_decode_step_fits_one_chip(one_chip, monkeypatch):
     weights = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(params))
     assert weights < 6.5e9                  # bf16: ~6.17 GB
     assert used < HBM_BYTES, used
+
+
+def test_full_width_step_keeps_the_names_a_trace_is_read_by(full_width_step):
+    """A device trace names the step's module and the decode attention
+    kernel's op as the compiled text does; the benchmark's step and kernel
+    metrics find their device time by these two names."""
+    text = full_width_step[0].as_text()
+    assert re.match(r"HloModule jit_fused_decode_step[,\s]", text), text[:80]
+    calls = re.findall(r"^\s*%(decode_attention_bkgd)(?:\.\d+)? = [^\n]*"
+                       r"custom-call\([^\n]*custom_call_target="
+                       r"\"tpu_custom_call\"", text, flags=re.M)
+    assert calls, "no decode_attention_bkgd custom call in the step"
 
 
 def test_sharded_topology_compiles_on_four_chips(topo, monkeypatch):
