@@ -186,21 +186,27 @@ def make_verify_step(cfg: ModelConfig):
     """Speculative-decode verify: feed a (B, W) window of tokens — per row,
     the committed next input token followed by up to W-1 draft proposals —
     through W chained decode steps in ONE jitted program, returning the
-    per-position greedy tokens (B, W) int32, the per-position logits
+    per-position sampled tokens (B, W) int32, the per-position logits
     (B, W, V), and the advanced cache.
 
     This is ``make_chunked_prefill_step``'s scan body promoted to a
     standalone step: each position runs the SAME ``LM.decode`` the plain
     tick runs, so the logits at position j (given the same fed prefix) are
-    bit-identical to the non-speculative path's — acceptance is exact-match
-    on sampled tokens, which is what makes speculative streams
-    bit-identical by construction across families, pools, and topologies.
-    The device cache advances W positions for every row; the engine rewinds
-    each row to its true position afterwards (``pool.set_index``) — the
-    same mechanism preemption/evacuation uses — so rejected-tail K/V is
-    simply re-covered.  jit retraces per distinct W; an engine uses one W.
+    bit-identical to the non-speculative path's.  Each lane draws its token
+    with the jnp sampler (``kernels.ref.fused_sample_ref``, pinned bitwise
+    to the fused step's kernel) from the row's (seed, rid) and that lane's
+    ``pos`` (B, W) counter — the draw the plain tick would make at the same
+    output position — so acceptance, exact-match on sampled tokens, keeps
+    speculative streams bit-identical to plain ones.  The device cache
+    advances W positions for every row; the engine rewinds each row to its
+    true position afterwards (``pool.set_index``) — the same mechanism
+    preemption/evacuation uses — so rejected-tail K/V is simply re-covered.
+    jit retraces per distinct W; an engine uses one W.
     """
-    def verify_step(params, tokens, cache):
+    from repro.kernels import ref as kernel_ref
+
+    def verify_step(params, tokens, cache, seed, rid, pos, temperature):
+        B, W = tokens.shape
         tail = jnp.moveaxis(tokens[:, :, None], 1, 0)     # (W, B, 1)
 
         def body(cache, tok):
@@ -212,37 +218,66 @@ def make_verify_step(cfg: ModelConfig):
         # costs more than the chained decodes themselves on CPU
         cache, ls = jax.lax.scan(body, cache, tail, unroll=True)
         ls = jnp.moveaxis(ls, 0, 1)                       # (B, W, V)
-        toks = jnp.argmax(ls.astype(jnp.float32), axis=-1).astype(jnp.int32)
-        return toks, ls, cache
+
+        def lanes(a):
+            return jnp.repeat(a, W)                       # (B·W,) row-major
+
+        toks = kernel_ref.fused_sample_ref(
+            ls.reshape(B * W, -1).astype(jnp.float32), lanes(seed),
+            lanes(rid), pos.reshape(-1), lanes(temperature))
+        return toks.reshape(B, W), ls, cache
 
     return verify_step
 
 
-def make_fused_decode_step(cfg: ModelConfig):
-    """One decode step with sampling fused into the tail: returns the
-    per-row sampled tokens (B,) int32 ALONGSIDE the logits, so a greedy
-    serving tick pulls B int32s instead of (B, 1, V) floats — the logits
-    stay device-resident for the rows (temperature > 0) that still sample
-    host-side with their stateful per-request RNG.
-
-    seed/rid/pos are (B,) int32 stateless RNG counters (unused by greedy
-    rows but threaded so device sampling is per-(request, position)
-    reproducible); temperature is (B,) float32, 0 → greedy argmax,
-    bit-compatible with the host ``sampling.sample_token``.  Dispatches to
-    the Pallas fused-sample kernel under ``cfg.use_pallas`` and to the jnp
-    oracle otherwise — the two are pinned bitwise-equal.
-    """
+def _row_sampler(cfg: ModelConfig):
+    """The sampler every serving step draws with: (B, V) float32 rows and
+    (B,) counters → (B,) int32 tokens.  The Pallas fused-sample kernel
+    under ``cfg.use_pallas``, the jnp oracle otherwise — the two are
+    pinned bitwise-equal."""
     from repro.kernels import ops as kernel_ops
     from repro.kernels import ref as kernel_ref
 
+    return (kernel_ops.fused_sample if cfg.use_pallas
+            else kernel_ref.fused_sample_ref)
+
+
+def make_sample_step(cfg: ModelConfig):
+    """Draw one token per row from a step's last-position logits (B, S, V)
+    with the fused decode step's sampler and counters: the admission's
+    first token off the prefill's (1, 1, V) logits, and the tokens of a
+    replaced decode step's (B, 1, V) logits.  One program per (B, V)."""
+    sample = _row_sampler(cfg)
+
+    def sample_step(logits, seed, rid, pos, temperature):
+        return sample(logits[:, -1].astype(jnp.float32), seed, rid, pos,
+                      temperature)
+
+    return sample_step
+
+
+def make_fused_decode_step(cfg: ModelConfig):
+    """One decode step with sampling fused into the tail: returns the
+    per-row sampled tokens (B,) int32 ALONGSIDE the logits, so a serving
+    tick pulls B int32s instead of (B, 1, V) floats.  The device draw is
+    the sampler: greedy rows and temperature rows over the full vocabulary
+    emit its token; the logits stay device-resident for the rows
+    (temperature > 0 with ``top_k > 0``) that still sample host-side with
+    their stateful per-request RNG.
+
+    seed/rid/pos are (B,) int32 stateless RNG counters, so every draw is a
+    pure function of (seed, request, output position); temperature is (B,)
+    float32, 0 → greedy argmax, bit-compatible with the host
+    ``sampling.sample_token``.  Dispatches to the Pallas fused-sample
+    kernel under ``cfg.use_pallas`` and to the jnp oracle otherwise — the
+    two are pinned bitwise-equal.
+    """
+    sample = _row_sampler(cfg)
+
     def fused_decode_step(params, tokens, cache, seed, rid, pos, temperature):
         logits, cache = LM.decode(params, tokens, cfg, cache)
-        rows = logits[:, 0].astype(jnp.float32)
-        if cfg.use_pallas:
-            toks = kernel_ops.fused_sample(rows, seed, rid, pos, temperature)
-        else:
-            toks = kernel_ref.fused_sample_ref(rows, seed, rid, pos,
-                                               temperature)
+        toks = sample(logits[:, 0].astype(jnp.float32), seed, rid, pos,
+                      temperature)
         return toks, logits, cache
 
     return fused_decode_step

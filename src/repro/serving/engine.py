@@ -17,18 +17,23 @@ from a naive batched decoder:
   validity mask (see slots.py for why the seed's shared scalar was wrong).
 
 * **Sampling layer.**  Greedy argmax is just the default SamplingParams;
-  temperature/top-k sampling is seeded per request (scheduler.Request).
-  Sampling is FUSED into the decode tail (steps.make_fused_decode_step):
-  greedy rows take the device-sampled token, so a greedy tick pulls (B,)
-  int32s instead of (B, 1, V) logits — only temperature rows pull their
-  one logits row to keep their stateful per-request host RNG.
+  temperature sampling is seeded per request (scheduler.Request).  The
+  device draw is the sampler: it is FUSED into the decode tail
+  (steps.make_fused_decode_step), Gumbel-max from stateless (seed, rid,
+  output position) counters, and greedy rows and temperature rows over the
+  full vocabulary emit its token — a tick pulls (B,) int32s instead of
+  (B, 1, V) logits, an admission one int32, and a request replays the same
+  stream after a preemption.  Only a temperature row with ``top_k > 0``
+  (which the kernel's one static k per call cannot serve) pulls its logits
+  row and draws with its stateful per-request host RNG.
 
 * **Speculative decoding** (``spec_k > 0``).  A model-free prompt-lookup
   draft (serving/draft.py) proposes up to k tokens per decode slot from
   the slot's own prompt+generated history; the target model verifies the
   whole window in ONE jitted multi-position decode (steps.make_verify_step)
   and the engine accepts the longest exact-match prefix — emitting a+1
-  tokens per tick where the plain path emits 1.  Rejected tails rewind via
+  tokens per tick where the plain path emits 1.  Each lane draws from the
+  counters of the output position it would emit.  Rejected tails rewind via
   the pool index vector (the same mechanism preemption uses), so rejected
   K/V is simply re-covered.  PREFILL rows ride the same window: up to W
   upcoming prompt tokens stream per tick.  Acceptance is exact-match on
@@ -58,7 +63,7 @@ from repro.models import LM
 from repro.models.attention import Attention
 from repro.models.steps import (
     make_decode_step, make_fused_decode_step, make_prefill_step,
-    make_verify_step,
+    make_sample_step, make_verify_step,
 )
 from repro.serving.draft import ngram_propose
 from repro.serving.scheduler import FCFSScheduler, Request
@@ -77,6 +82,14 @@ def _reading(now):
 def _rid(req) -> int:
     """The request id a span carries (-1 for a slot with no Request)."""
     return req.rid if isinstance(req, Request) else -1
+
+
+def _host_draws(req) -> bool:
+    """A temperature row with ``top_k > 0`` draws on the host: the device
+    sampler takes one static k per call, not a per-row one.  Every other
+    row takes the device's draw."""
+    return (isinstance(req, Request) and req.sampling.temperature > 0.0
+            and req.sampling.top_k > 0)
 
 
 class EngineCore:
@@ -99,6 +112,9 @@ class EngineCore:
         self.fused_decode = jax.jit(make_fused_decode_step(cfg),
                                     donate_argnums=(2,))
         self.verify = jax.jit(make_verify_step(cfg), donate_argnums=(2,))
+        # the same draw over given logits: an admission's first token, a
+        # replaced decode step's tokens
+        self.sample = jax.jit(make_sample_step(cfg))
 
 
 class EngineStats:
@@ -113,10 +129,12 @@ class EngineStats:
         self.total_spec_proposed = 0
         self.total_spec_accepted = 0
         # host traffic: tokens appended to requests' outputs, and the bytes
-        # (and logits rows) the engine materialized from the device
+        # (and logits rows) the engine materialized from the device; of the
+        # temperature tokens, those taken from the device's draw
         self.total_emitted = 0
         self.total_pulled_bytes = 0
         self.total_rows_pulled = 0
+        self.total_device_draws = 0
         self.completed_by_tier: dict[str, int] = {}
         self.latencies_ms = deque(maxlen=4096)
         self.queue_depth = 0
@@ -134,6 +152,7 @@ class EngineStats:
         self._win_emitted = 0
         self._win_pulled_bytes = 0
         self._win_rows_pulled = 0
+        self._win_device_draws = 0
 
     def on_tick(self, busy_slots: int, slots: int, queue_depth: int):
         self.total_ticks += 1
@@ -161,9 +180,13 @@ class EngineStats:
         self._win_spec_prop += proposed
         self._win_spec_acc += accepted
 
-    def on_emit(self):
+    def on_emit(self, device_draw: bool = False):
+        """One token appended to a request's output; ``device_draw``: a
+        temperature token the device drew."""
         self.total_emitted += 1
         self._win_emitted += 1
+        self.total_device_draws += device_draw
+        self._win_device_draws += device_draw
 
     def on_pull(self, nbytes: int, rows: int = 0):
         """One device array materialized on the host: its device bytes
@@ -194,6 +217,7 @@ class EngineStats:
             "emitted_tokens": self._win_emitted,
             "pulled_bytes": self._win_pulled_bytes,
             "rows_pulled": self._win_rows_pulled,
+            "device_draws": self._win_device_draws,
         }
         self._reset_window()
         return out
@@ -259,7 +283,7 @@ class ServingEngine:
         self._tokens_host = np.zeros(slots, np.int32)
         # host-side token truth may run ahead of the staged device copy:
         # verify ticks build their window from _tokens_host directly, so
-        # they defer the (slots, 1) device put until a fused/legacy tick
+        # they defer the (slots, 1) device put until a fused tick
         # (or admission) actually needs self.tokens
         self._tokens_dirty = False
         self.pos = np.zeros(slots, np.int64)        # per-slot position
@@ -446,15 +470,8 @@ class ServingEngine:
         if request is not None:
             self.slot_owner[slot] = request
         if c == P:
-            # the admission's row counts its bytes, not as a tick's pull
-            row = self._pull_row(logits, (0, -1), slot, rows=0)
-            if request is not None:
-                with span("serve.host_draw", rid=request.rid, slot=slot):
-                    tok = request.sample(row)
-                self.stats.on_emit()
-            else:
-                tok = int(np.argmax(row))
-            self._tokens_host[slot] = tok
+            self._tokens_host[slot] = self._first_token(slot, request,
+                                                        logits)
             self.phase[slot] = PHASE_DECODE
         else:
             self._tokens_host[slot] = int(prompt[c])
@@ -462,49 +479,55 @@ class ServingEngine:
             self.phase[slot] = PHASE_PREFILL
         self._stage_tokens()
 
+    def _first_token(self, slot: int, req, logits) -> int:
+        """The first generated token, off the prefill's (1, 1, V) logits:
+        the device's draw from the request's counters, one int32 pulled;
+        a ``top_k > 0`` temperature request pulls the row (its bytes
+        counted, not as a tick's pull) and draws on the host."""
+        if _host_draws(req):
+            row = self._pull_row(logits, (0, -1), slot, rows=0)
+            with span("serve.host_draw", rid=req.rid, slot=slot):
+                tok = req.sample(row)
+            self.stats.on_emit()
+            return tok
+        toks = self.core.sample(logits, *self._counters([(0, req)], 1))
+        tok = int(np.asarray(toks)[0])
+        self.stats.on_pull(toks.nbytes)
+        if isinstance(req, Request):
+            req.tokens_out.append(tok)
+            self.stats.on_emit(device_draw=req.sampling.temperature > 0.0)
+        return tok
+
     def tick(self, now: float | None = None) -> list[int]:
         """One decode step for all slots (inactive slots decode garbage that
         is simply ignored).  Returns slots that finished this tick.
 
-        Three paths, one contract (bit-identical token streams):
+        Two paths, one contract (bit-identical token streams):
 
-        * **legacy** — ``self.decode`` was replaced (sharded topologies
-          install their own compiled step; tests monkeypatch): bulk-pull the
-          (slots, 1, V) logits and sample on host, as the seed did.
-        * **fused** — sampling runs in the decode tail on device; greedy
-          rows never materialize logits on host (the engine pulls (slots,)
-          int32 tokens), temperature rows pull only their one (V,) row.
+        * **fused** — sampling runs in the decode tail on device; the
+          engine pulls (slots,) int32 tokens, and only a ``top_k > 0``
+          temperature row pulls its one (V,) row to draw on the host.  A
+          replaced ``self.decode`` (sharded topologies install their own
+          compiled step; tests monkeypatch) runs in the fused step's place,
+          and the same draw runs over its (slots, 1, V) logits.
         * **verify** — when speculation is on and a draft (or a streamable
           prompt tail) exists, ONE multi-position decode verifies a whole
           (slots, W) window and the engine emits the accepted prefix.
+
+        Both draw from the same (seed, rid, output position) counters, so
+        every topology emits the same stream.
         """
         if not self.active.any():
             return []
         with span("serve.tick"):
-            if self.decode is not self.core.decode:
-                return self._tick_legacy(now)
-            if self._spec_ok:
+            # a replaced step is compiled for (slots, 1) decode only
+            if self._spec_ok and self.decode is self.core.decode:
                 drafts, window_w = self._plan_window()
                 if window_w >= 2:
                     return self._tick_verify(drafts, window_w, now)
             return self._tick_fused(now)
 
     # -------------------------------------------------- shared tick plumbing
-
-    def _tick_legacy(self, now) -> list[int]:
-        """A replaced decode step: bulk-pull (slots, V) rows, host argmax."""
-        with span("serve.decode_dispatch"):
-            if self._tokens_dirty:
-                self._stage_tokens()
-            logits, cache = self.decode(self.params, self.tokens,
-                                        self.pool.cache)
-            self.pool.cache = cache
-        with span("serve.decode_wait"):
-            dev = logits[:, 0]
-            rows = np.asarray(dev, np.float32)              # (slots, V)
-        self.stats.on_pull(dev.nbytes, rows=1)
-        toks = np.argmax(rows, axis=1).astype(np.int32)
-        return self._advance(toks, lambda s: rows[s], now)
 
     def _pull_row(self, logits, index, slot: int, rows: int = 1):
         """One logits row, ``logits[index]``, to the host as float32; its
@@ -516,25 +539,43 @@ class ServingEngine:
         self.stats.on_pull(dev.nbytes, rows=rows)
         return row
 
+    def _counters(self, owners, n: int):
+        """The sampler's (n,) seed, rid, pos and temperature for rows
+        ``owners`` [(row, req)]: pos is the output position the draw
+        fills, so a draw is a pure function of (seed, rid, position)."""
+        seed = np.zeros(n, np.int32)
+        rid = np.zeros(n, np.int32)
+        pos = np.zeros(n, np.int32)
+        temp = np.zeros(n, np.float32)
+        for row, req in owners:
+            if isinstance(req, Request):
+                seed[row] = req.sampling.seed
+                rid[row] = req.rid
+                pos[row] = len(req.tokens_out)
+                temp[row] = req.sampling.temperature
+        return seed, rid, pos, temp
+
     def _stage_tokens(self):
         """Materialize the device copy of every slot's next input token."""
         self.tokens = jnp.asarray(self._tokens_host[:, None])
         self._tokens_dirty = False
 
     def _emit(self, slot: int, req, tok_dev: int, fetch_row) -> int:
-        """One sampled token for a slot, device-first: greedy rows take the
-        device-sampled token (bit-equal to host argmax), temperature rows
-        pull their one logits row and keep their stateful host RNG."""
+        """One sampled token for a slot: the device's draw (greedy rows
+        bit-equal to host argmax, temperature rows Gumbel-max over the full
+        vocabulary); a ``top_k > 0`` temperature row instead pulls its one
+        logits row and draws with its stateful host RNG."""
         if not isinstance(req, Request):
             return int(tok_dev)
-        if req.sampling.temperature > 0.0:
+        if _host_draws(req):
             row = fetch_row(slot)
             with span("serve.host_draw", rid=req.rid, slot=slot):
                 tok = req.sample(row)
+            self.stats.on_emit()
         else:
             tok = int(tok_dev)
             req.tokens_out.append(tok)
-        self.stats.on_emit()
+            self.stats.on_emit(device_draw=req.sampling.temperature > 0.0)
         return tok
 
     def _advance(self, toks_host, fetch_row, now) -> list[int]:
@@ -579,26 +620,23 @@ class ServingEngine:
 
     def _tick_fused(self, now) -> list[int]:
         """One decode step with sampling fused into the decode tail: the
-        kernel draws from stateless (seed, rid, pos) counters per row, and a
-        greedy tick pulls (slots,) int32 tokens — zero host logits traffic."""
-        B = self.slots
+        kernel draws from stateless (seed, rid, pos) counters per row, and
+        the tick pulls (slots,) int32 tokens — no host logits traffic
+        unless a ``top_k > 0`` temperature row asks for its row.  A
+        replaced decode step takes the fused step's place, and the same
+        draw runs over its logits."""
         with span("serve.decode_dispatch"):
-            seed = np.zeros(B, np.int32)
-            rid = np.zeros(B, np.int32)
-            pos = np.zeros(B, np.int32)
-            temp = np.zeros(B, np.float32)
-            for slot, req in self.slot_owner.items():
-                if isinstance(req, Request):
-                    seed[slot] = req.sampling.seed
-                    rid[slot] = req.rid
-                    pos[slot] = len(req.tokens_out)
-                    temp[slot] = req.sampling.temperature
+            counters = [jnp.asarray(a) for a in self._counters(
+                self.slot_owner.items(), self.slots)]
             if self._tokens_dirty:
                 self._stage_tokens()
-            toks, logits, cache = self.core.fused_decode(
-                self.params, self.tokens, self.pool.cache,
-                jnp.asarray(seed), jnp.asarray(rid), jnp.asarray(pos),
-                jnp.asarray(temp))
+            if self.decode is self.core.decode:
+                toks, logits, cache = self.core.fused_decode(
+                    self.params, self.tokens, self.pool.cache, *counters)
+            else:
+                logits, cache = self.decode(self.params, self.tokens,
+                                            self.pool.cache)
+                toks = self.core.sample(logits, *counters)
             self.pool.cache = cache
         with span("serve.decode_wait"):
             toks_host = np.asarray(toks)                # (slots,) int32
@@ -650,7 +688,11 @@ class ServingEngine:
 
         Lane 0 is every slot's staged token (what the plain tick would have
         fed); decode lanes 1.. carry that slot's draft, prefill lanes carry
-        upcoming prompt tokens.  After the device pass the engine accepts
+        upcoming prompt tokens.  Decode lane j draws at output position
+        ``len(tokens_out) + j``, the position the plain tick would fill
+        after j accepted tokens; a prefill row draws at its base position
+        in every lane, the one it emits at.  After the device pass the
+        engine accepts
         the longest exact-match draft prefix per slot and REWINDS the pool
         index vector to the authoritative host positions — unconsumed lanes
         simply get re-covered by later writes, the same mechanism preemption
@@ -675,8 +717,14 @@ class ServingEngine:
                     d = drafts[slot][:W - 1]
                     window[slot, 1:1 + len(d)] = d
                     n_draft[slot] = len(d)
+            seed, rid, pos, temp = self._counters(self.slot_owner.items(), B)
+            lane_pos = np.repeat(pos[:, None], W, axis=1)
+            lane_pos[self.phase == PHASE_DECODE] += np.arange(W,
+                                                             dtype=np.int32)
             toks, logits, cache = self.core.verify(
-                self.params, jnp.asarray(window), self.pool.cache)
+                self.params, jnp.asarray(window), self.pool.cache,
+                jnp.asarray(seed), jnp.asarray(rid), jnp.asarray(lane_pos),
+                jnp.asarray(temp))
             self.pool.cache = cache
         with span("serve.decode_wait"):
             toks_host = np.asarray(toks)                # (slots, W) int32
@@ -701,7 +749,7 @@ class ServingEngine:
         # padding) lanes' device writes fall past the new horizon.  The
         # next-token device copy is NOT re-staged here — the next verify
         # window reads _tokens_host directly, so the put is deferred until
-        # a fused/legacy tick (or admission) needs it.
+        # a fused tick (or admission) needs it.
         self.pool.set_index(self.pos.astype(np.int32))
         self._tokens_dirty = True
         return done
@@ -741,14 +789,16 @@ class ServingEngine:
         """A DECODE slot with m draft lanes: accept the longest prefix where
         the model's sampled token equals the draft, emit a+1 tokens.  Exact-
         match acceptance keeps streams bit-identical for ANY sampling mode —
-        temperature rows sample each lane with their stateful host RNG (one
-        draw per emitted token, same as the plain path) and accept iff the
-        sample agrees with the draft."""
+        each lane's device draw is the plain tick's draw at the same output
+        position, and a ``top_k > 0`` temperature row samples each lane with
+        its stateful host RNG (one draw per emitted token, same as the plain
+        path); either way a lane is accepted iff its token equals the
+        draft."""
         a = 0
         for j in range(m + 1):
             # one simulated plain tick per lane: decrement, maybe complete
             # (the plain path's completing tick samples NOTHING — neither
-            # may this one, or temperature RNG streams would diverge)
+            # may this one, or host RNG streams would diverge)
             self.pos[slot] += 1
             self.remaining[slot] -= 1
             if self.remaining[slot] <= 0:
@@ -828,6 +878,7 @@ class ServingEngine:
             "logits_pulls": int(self.stats.total_rows_pulled),
             "emitted_tokens": int(self.stats.total_emitted),
             "pulled_bytes": int(self.stats.total_pulled_bytes),
+            "device_draws": int(self.stats.total_device_draws),
         }
         if self._paged:
             out["prefix_hits"] = int(self.pool.n_prefix_hits)
@@ -839,8 +890,8 @@ class ServingEngine:
 
     @property
     def logits_pulls(self) -> int:
-        """Logits pulls by decode ticks: one per sampled row, one per bulk
-        (slots, V) pull of a replaced decode step."""
+        """Logits rows pulled by decode ticks: one per host-drawn token (a
+        ``top_k > 0`` temperature row)."""
         return self.stats.total_rows_pulled
 
     @property

@@ -283,7 +283,7 @@ def mesh_core(cfg, max_seq: int, mesh, *, seed: int = 0) -> EngineCore:
     """An EngineCore whose weights sit replicated on every device of
     ``mesh`` — placed once, instead of being re-broadcast from the default
     device by every sharded decode call — with ``make_sharded_prefill`` as
-    its prefill."""
+    its prefill and ``make_sharded_sample`` as its sampler."""
     import jax
     from jax.sharding import NamedSharding, PartitionSpec
 
@@ -291,7 +291,25 @@ def mesh_core(cfg, max_seq: int, mesh, *, seed: int = 0) -> EngineCore:
     core.params = jax.device_put(core.params,
                                  NamedSharding(mesh, PartitionSpec()))
     core.prefill = make_sharded_prefill(cfg, mesh, max_seq)
+    core.sample = make_sharded_sample(cfg)
     return core
+
+
+def make_sharded_sample(cfg):
+    """The engine's sample step (``EngineCore.sample``) for logits that
+    span a mesh — replicated by the sharded prefill, slot-sharded by the
+    sharded decode: the jnp sampler, which XLA partitions like any
+    program.  The Pallas kernel cannot be partitioned automatically; the
+    two samplers are pinned bitwise-equal, so the stream is the one a
+    single-device engine draws."""
+    import dataclasses
+
+    import jax
+
+    from repro.models.steps import make_sample_step
+
+    return jax.jit(make_sample_step(dataclasses.replace(cfg,
+                                                        use_pallas=False)))
 
 
 def make_sharded_prefill(cfg, mesh, max_seq: int):
@@ -405,8 +423,8 @@ class ShardedReplica(InProcessReplica):
         # paged allocator partitions track the mesh: slot s draws blocks
         # only from its own shard's contiguous block range, so the sharded
         # decode body's global→local block-id fold stays exact
-        # spec knobs are accepted but inert here: replacing engine.decode
-        # below routes every tick down the legacy bulk-pull path (the
+        # spec knobs are accepted but inert here: with engine.decode
+        # replaced below, every tick is a single-position tick (the
         # sharded step is compiled for (slots, 1) decode only)
         engine = ServingEngine(cfg, slots=slots, max_seq=max_seq, seed=seed,
                                prefill_chunk=prefill_chunk, core=core,

@@ -1,10 +1,13 @@
 """Sampling layer: temperature / top-k / greedy, seeded per request.
 
-Sampling runs on the host over the one row of logits each slot produced this
-tick — at serving time the (slots, 1, V) logits are already being pulled back
-for lifecycle bookkeeping, so host-side numpy keeps the device tick a pure
-fixed-shape decode (the TPU-friendly form) while every request still gets its
-own reproducible RNG.
+The device draw is the sampler: the serving engine emits the token the
+decode step's fused sampler drew (``models.steps.make_fused_decode_step``,
+Gumbel-max from stateless (seed, rid, position) counters) for greedy rows
+and for temperature rows over the full vocabulary.  This module's host draw
+serves only what the device sampler lacks, a per-row ``top_k > 0``: such a
+row pulls its one logits row and draws here with the request's stateful
+numpy RNG (``Request.sample``).  Greedy argmax here is bit-compatible with
+the device's, which tests pin.
 """
 from __future__ import annotations
 

@@ -18,7 +18,8 @@ Operators read them from the trace by name (docs/operations.md):
   serve.decode_dispatch  the tick's inputs built, put on the device, the
                          step enqueued
   serve.decode_wait      host blocked until the step's tokens are on it
-  serve.row_pull         one logits row to the host (rid, slot)
+  serve.row_pull         one logits row to the host (rid, slot): a
+                         ``top_k > 0`` temperature row's
   serve.host_draw        one host-side draw from a pulled row (rid, slot)
 
 A tick's own bookkeeping (slot advance, next-token staging) is its self

@@ -238,7 +238,9 @@ class PodRuntime:
             init_distributed, local_pod_mesh, spmd_across_processes,
         )
         from repro.serving.engine import ServingEngine
-        from repro.serving.replica import make_sharded_decode
+        from repro.serving.replica import (
+            make_sharded_decode, make_sharded_sample,
+        )
 
         if self.size > 1 and self.coordinator:
             init_distributed(self.coordinator, self.size, self.rank)
@@ -267,6 +269,7 @@ class PodRuntime:
         engine.decode = make_sharded_decode(cfg, mesh, slots, max_seq,
                                             pool=pool, block_size=block_size,
                                             num_blocks=num_blocks)
+        engine.core.sample = make_sharded_sample(cfg)
         return engine
 
     def info(self) -> dict:

@@ -14,7 +14,9 @@ import numpy as np
 import pytest
 
 from repro.models import LM
-from repro.models.steps import make_chunked_prefill_step, make_prefill_step
+from repro.models.steps import (
+    make_chunked_prefill_step, make_prefill_step, make_sample_step,
+)
 from repro.serving import (
     Request, SamplingParams, ServingEngine, SlotPool, sample_token,
 )
@@ -451,3 +453,108 @@ def test_temperature_zero_matches_greedy_engine_default():
     [d1] = run_to_completion(e1, 1)
     [d2] = run_to_completion(e2, 1)
     assert d1.tokens_out == d2.tokens_out
+
+
+def test_device_temperature_stream_is_keyed_by_seed_rid_and_position():
+    """A full-vocabulary temperature stream is the device's draw from
+    (seed, rid, output position): two engines emit the same tokens for the
+    same (seed, rid), whatever slot and neighbour the request has; a
+    request preempted mid-stream and admitted again replays the stream;
+    another seed draws another."""
+    def request(seed=5):
+        [r] = make_requests("dense", 1, gen_len=8, seed=3,
+                            sampling=SamplingParams(temperature=0.8,
+                                                    seed=seed))
+        return r
+
+    alone = make_engine("dense", slots=1)
+    alone.submit(request(), now=0.0)
+    [want] = run_to_completion(alone, 1)
+    assert len(set(want.tokens_out)) > 1          # a draw, not a constant
+
+    beside = make_engine("dense", slots=2)
+    [neighbour] = make_requests("dense", 1, gen_len=8, seed=9)
+    neighbour.rid = 7
+    beside.submit(neighbour, now=0.0)            # takes slot 0
+    beside.submit(request(), now=0.0)
+    got = {r.rid: r for r in run_to_completion(beside, 2)}
+    assert got[0].tokens_out == want.tokens_out
+
+    again = make_engine("dense", slots=1)
+    r = request()
+    again.submit(r, now=0.0)
+    now = 0.0
+    while len(r.tokens_out) < 3:
+        now += 1.0
+        again.step(now=now)
+    assert again.preempt_slot(0) is r and r.tokens_out == []
+    again.submit(r, now=now)
+    [replayed] = run_to_completion(again, 1)
+    assert replayed.tokens_out == want.tokens_out
+
+    other = make_engine("dense", slots=1)
+    other.submit(request(seed=6), now=0.0)
+    [reseeded] = run_to_completion(other, 1)
+    assert reseeded.tokens_out != want.tokens_out
+
+
+@pytest.mark.parametrize("sampling,row_pulled,device_draws", [
+    (SamplingParams(), False, 0),
+    (SamplingParams(temperature=0.8, seed=2), False, 1),
+    (SamplingParams(temperature=0.8, top_k=4, seed=2), True, 0),
+])
+def test_admission_pulls_one_int32_not_a_row(sampling, row_pulled,
+                                              device_draws):
+    """An admission whose prompt fits one prefill draws its first token on
+    the device and pulls 4 bytes; only a ``top_k > 0`` temperature request
+    pulls the (V,) float32 row to draw on the host.  A greedy first token
+    is the prefill logits' argmax."""
+    V = TINY_CFGS["dense"].vocab
+    eng = make_engine("dense", slots=1)
+    [r] = make_requests("dense", 1, sampling=sampling)
+    eng.admit(0, r.prompt, r.gen_len, request=r)
+    life = eng.lifetime()
+    assert len(r.tokens_out) == life["emitted_tokens"] == 1
+    assert life["pulled_bytes"] == (4 * V if row_pulled else 4)
+    assert life["logits_pulls"] == 0             # not a tick's pull
+    assert life["device_draws"] == device_draws
+    if sampling.temperature == 0.0:
+        logits, _ = eng.prefill(eng.params,
+                                {"tokens": jnp.asarray(r.prompt[None])})
+        assert r.tokens_out[0] == int(np.argmax(np.asarray(logits[0, -1])))
+    bare = make_engine("dense", slots=1)         # no Request: greedy
+    bare.admit(0, r.prompt, r.gen_len)
+    assert bare.lifetime()["pulled_bytes"] == 4
+
+
+# the upper 1e-6 tail of chi-square with 31 degrees of freedom
+CHI2_LIMIT_31 = 83.6
+
+
+@pytest.mark.parametrize("use_pallas", [False, True])
+@pytest.mark.parametrize("temperature", [0.7, 1.0])
+def test_device_draw_follows_softmax_at_temperature(temperature, use_pallas):
+    """The device draw at T samples softmax(x / T): one fixed row of 32
+    logits drawn at 4096 (rid, pos) counters gives token counts that pass a
+    chi-square test against softmax(x / T) and fail it against the other
+    temperature's softmax (the test can tell the two apart)."""
+    V, N = 32, 4096
+    x = np.random.default_rng(0).permutation(
+        np.linspace(-1.0, 1.0, V)).astype(np.float32)
+    cfg = dataclasses.replace(TINY_CFGS["dense"], use_pallas=use_pallas)
+    sample = jax.jit(make_sample_step(cfg))
+    rid = np.repeat(np.arange(64, dtype=np.int32), N // 64)
+    pos = np.tile(np.arange(N // 64, dtype=np.int32), 64)
+    toks = np.asarray(sample(jnp.asarray(np.broadcast_to(x, (N, 1, V))),
+                             np.full(N, 7, np.int32), rid, pos,
+                             np.full(N, temperature, np.float32)))
+    counts = np.bincount(toks, minlength=V)
+    assert counts.sum() == N and counts.size == V
+
+    def chi2(t):
+        p = np.exp(x / t - np.max(x / t))
+        expected = N * p / p.sum()                # >= 39 per token here
+        return float(np.sum((counts - expected) ** 2 / expected))
+
+    assert chi2(temperature) < CHI2_LIMIT_31
+    assert chi2(1.7 - temperature) > CHI2_LIMIT_31

@@ -29,7 +29,9 @@ def core():
 
 
 def _requests(prompt=None):
-    """Two greedy and two sampled requests, each fitting one prefill."""
+    """Two greedy and two sampled requests, each fitting one prefill; of
+    the sampled, one draws over the full vocabulary (on the device) and one
+    over its top 8 (on the host)."""
     rng = np.random.default_rng(0)
     out = []
     for i in range(4):
@@ -37,7 +39,8 @@ def _requests(prompt=None):
              rng.integers(3, V, size=PROMPT).astype(np.int32))
         out.append(Request(rid=100 + i, prompt=p, gen_len=GEN,
                            sampling=SamplingParams(
-                               temperature=0.8 if i % 2 else 0.0, seed=i)))
+                               temperature=0.8 if i % 2 else 0.0,
+                               top_k=8 if i == 3 else 0, seed=i)))
     return out
 
 
@@ -104,6 +107,9 @@ def test_spans_nest_carry_rids_and_counters_match_shapes(core, path,
 
     by_rid = {r.rid: r for r in reqs}
     sampled = {r.rid for r in reqs if r.sampling.temperature > 0}
+    host_drawn = {r.rid for r in reqs if r.rid in sampled
+                  and r.sampling.top_k > 0}
+    assert host_drawn and sampled - host_drawn
     for sp in spans:
         name, meta = sp[2], sp[3]
         if name in ("serve.row_pull", "serve.host_draw"):
@@ -118,29 +124,36 @@ def test_spans_nest_carry_rids_and_counters_match_shapes(core, path,
             assert len(_parents(sp, spans, "serve.tick")) == 1, sp
         if name in ("serve.prefill", "serve.pool_write"):
             assert len(_parents(sp, spans, "serve.admit")) == 1, sp
-    # a host draw is a sampled request's; an admission's spans share its rid
+    # a host draw is a top_k request's; a full-vocabulary sampled request
+    # takes the device's draw and pulls no row; an admission's spans share
+    # its rid
+    pulls = {sp[3]["rid"] for sp in spans
+             if sp[2] in ("serve.row_pull", "serve.host_draw")}
+    assert pulls == host_drawn
     draws = [sp for sp in spans if sp[2] == "serve.host_draw"]
     in_ticks = [sp for sp in draws if _parents(sp, spans, "serve.tick")]
-    assert {sp[3]["rid"] for sp in in_ticks} == sampled
+    assert {sp[3]["rid"] for sp in in_ticks} == host_drawn
     for adm in (sp for sp in spans if sp[2] == "serve.admit"):
         inner = [sp for sp in spans if _inside(sp, adm)
                  and sp[2] in ("serve.row_pull", "serve.host_draw")]
-        assert inner and {sp[3]["rid"] for sp in inner} == {adm[3]["rid"]}
+        assert {sp[3]["rid"] for sp in inner} == (
+            {adm[3]["rid"]} & host_drawn)
+        assert bool(inner) == (adm[3]["rid"] in host_drawn)
 
     win, life = eng.stats.drain_window(), eng.lifetime()
     emitted = sum(len(r.tokens_out) for r in done)
     assert win["emitted_tokens"] == life["emitted_tokens"] == emitted \
         == len(reqs) * GEN
-    admissions = len(reqs)
+    assert win["device_draws"] == life["device_draws"] \
+        == len(sampled - host_drawn) * GEN
     ticks = sum(1 for sp in spans if sp[2] == "serve.tick")
     row = V * 4                                  # one float32 logits row
-    if path == "fused":
-        tick_rows = len(sampled) * (GEN - 1)     # the admission drew one
-        want = ticks * SLOTS * 4 + (tick_rows + admissions) * row
+    # an admission pulls one int32, or a host-drawn request's row
+    admitted = (len(reqs) - len(host_drawn)) * 4 + len(host_drawn) * row
+    if path in ("fused", "legacy"):
+        tick_rows = len(host_drawn) * (GEN - 1)  # the admission drew one
+        want = ticks * SLOTS * 4 + tick_rows * row + admitted
         assert win["rows_pulled"] == eng.logits_pulls == tick_rows
-    elif path == "legacy":
-        want = ticks * SLOTS * row + admissions * row
-        assert win["rows_pulled"] == eng.logits_pulls == ticks
     else:
         # (slots, W) int32 tokens per verify tick, W >= 2
         assert eng.stats.total_spec_proposed > 0
@@ -148,7 +161,7 @@ def test_spans_nest_carry_rids_and_counters_match_shapes(core, path,
         assert win["rows_pulled"] == eng.logits_pulls == tick_rows
         want = None
         assert win["pulled_bytes"] >= \
-            ticks * SLOTS * 4 + (tick_rows + admissions) * row
+            ticks * SLOTS * 4 + tick_rows * row + admitted
     if want is not None:
         assert win["pulled_bytes"] == life["pulled_bytes"] == want
     assert life["logits_pulls"] == eng.logits_pulls

@@ -137,20 +137,48 @@ def test_spec_matches_plain_paged_pool():
 
 
 def test_greedy_decode_pulls_no_host_logits():
-    """The fused in-kernel sampler keeps greedy ticks devicebound: a plain
-    greedy run materializes ZERO host logits rows; a temperature run pulls
-    (host sampling is the contract there)."""
+    """The fused in-kernel sampler keeps ticks devicebound: a plain greedy
+    run materializes ZERO host logits rows, and so does a temperature run
+    over the full vocabulary, whose every token is the device's draw; a
+    ``top_k > 0`` temperature run pulls (host sampling is its contract)."""
     eng = make_engine("dense", slots=2)
     for r in echo_requests("dense", 2):
         eng.submit(r, now=0.0)
     run_to_completion(eng, 2)
     assert eng.logits_pulls == 0
+    assert eng.stats.total_device_draws == 0
     hot = make_engine("dense", slots=2)
     for r in echo_requests("dense", 2,
                            sampling=SamplingParams(temperature=0.9, seed=1)):
         hot.submit(r, now=0.0)
-    run_to_completion(hot, 2)
-    assert hot.logits_pulls > 0
+    out = run_to_completion(hot, 2)
+    assert hot.logits_pulls == 0
+    assert hot.stats.total_device_draws == sum(map(len, out.values())) \
+        == hot.lifetime()["device_draws"] == 20
+    top = make_engine("dense", slots=2)
+    for r in echo_requests("dense", 2, sampling=SamplingParams(
+            temperature=0.9, top_k=8, seed=1)):
+        top.submit(r, now=0.0)
+    run_to_completion(top, 2)
+    assert top.logits_pulls > 0
+    assert top.stats.total_device_draws == 0
+
+
+@pytest.mark.parametrize("prefill_chunk", [None, 4])
+@pytest.mark.parametrize("temperature", [0.05, 0.3])
+def test_spec_matches_plain_device_temperature(temperature, prefill_chunk):
+    """Full-vocabulary temperature rows take the device's draw in both
+    paths: each verify lane draws from the counters of the output position
+    it would emit, so the stream equals the plain tick's — with accepted
+    drafts in the run, and with prompt tails streamed through the window
+    (``prefill_chunk``), whose first token is drawn at position 0."""
+    sampling = SamplingParams(temperature=temperature, seed=11)
+    want, got, spec = run_pair(
+        "dense", lambda: echo_requests("dense", 2, sampling=sampling),
+        prefill_chunk=prefill_chunk)
+    assert got == want
+    assert spec.stats.total_spec_accepted > 0
+    assert spec.logits_pulls == 0
 
 
 # ------------------------------------------------------- rejected-tail rewind

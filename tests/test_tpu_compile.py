@@ -170,15 +170,17 @@ def test_full_width_step_keeps_the_names_a_trace_is_read_by(full_width_step):
 
 
 def test_sharded_topology_compiles_on_four_chips(topo, monkeypatch):
-    """The ``sharded`` topology's two programs over a ("data",) mesh of the
+    """The ``sharded`` topology's programs over a ("data",) mesh of the
     four chips: the replicated-weight prefill (Mosaic kernels cannot be
-    partitioned by XLA, so it must run under shard_map) and the
-    slot-sharded decode step."""
+    partitioned by XLA, so it must run under shard_map), the slot-sharded
+    decode step, and its sampler over either's logits."""
     import numpy as np
     from jax.sharding import AxisType, Mesh, NamedSharding
     from jax.sharding import PartitionSpec as P
 
-    from repro.serving.replica import make_sharded_decode, make_sharded_prefill
+    from repro.serving.replica import (
+        make_sharded_decode, make_sharded_prefill, make_sharded_sample,
+    )
 
     monkeypatch.setattr(ops, "_interpret_default", lambda: False)
     mesh = Mesh(np.array(topo.devices).reshape(4), ("data",),
@@ -197,3 +199,9 @@ def test_sharded_topology_compiles_on_four_chips(topo, monkeypatch):
     for compiled in (prefill, decode):
         assert "tpu_custom_call" in compiled.as_text()
         assert compiled.memory_analysis().argument_size_in_bytes < HBM_BYTES
+    sample = make_sharded_sample(cfg)
+    for n, rows in ((1, rep), (B, NamedSharding(mesh, P("data")))):
+        vec = lambda d: jax.ShapeDtypeStruct((n,), d, sharding=rep)  # noqa
+        sample.lower(jax.ShapeDtypeStruct((n, 1, cfg.vocab), BF16,
+                                          sharding=rows),
+                     vec(I32), vec(I32), vec(I32), vec(F32)).compile()
